@@ -244,7 +244,9 @@ SUITES = {
 
 def main() -> None:
     small = "--full" not in sys.argv
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if "--suite" in sys.argv:
         i = sys.argv.index("--suite") + 1

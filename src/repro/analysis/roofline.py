@@ -42,9 +42,10 @@ class MiningTilePlan:
     """Tile choice for the fused mine+screen kernel (kernels/tspm_fused).
 
     ``pb x ti x tj`` is the pair-tile grid shared with tspm_pairgen /
-    tspm_delta; ``bt`` the bucket-tile width of the VMEM-accumulated
-    [2^H] table; ``block_patients`` the host-loop patient block bounding
-    the corpus-free counting pass's working set."""
+    tspm_delta; ``bt`` the width of the VMEM-resident bucket table the
+    kernel accumulates (the whole [2^H] table, capped at the kernel
+    regime's 2^14); ``block_patients`` the host-loop patient block
+    bounding the corpus-free counting pass's working set."""
 
     pb: int
     ti: int
@@ -55,22 +56,30 @@ class MiningTilePlan:
     source: str              # 'analytic' | 'measured'
 
 
-def fused_kernel_vmem(pb: int, ti: int, tj: int, bt: int, max_events: int,
-                      chunk_i: int = 4) -> int:
+# largest bucket table the fused kernel keeps in VMEM: its one-hot matmul
+# histogram costs O(pairs * 2^H) MXU work, and past 2^14 buckets the jnp
+# block fallback wins (kernels/tspm_fused/ops.KERNEL_MAX_LOG2)
+FUSED_MAX_LOG2 = 14
+
+
+def fused_kernel_vmem(pb: int, ti: int, tj: int, bt: int,
+                      max_events: int) -> int:
     """Modeled VMEM bytes of one fused-kernel grid step.
 
     Rows (full-width phenx for the dedup lookback), the i/j row tiles, the
-    [Pb, T, E] dedup compare scratch, the pair-tile hash/flag planes, and
-    the [Pb, chunk_i * Tj, bt] compare-and-reduce slab of the histogram
-    accumulation loop.
+    [Pb, T, E] dedup compare scratch (32-bit masks), the pair-tile
+    hash/flag planes plus their int32 scratch copy, the bf16 one-hot
+    planes of one 8-row histogram matmul, and the int32 table with its
+    f32 accumulator.
     """
     e = max(ti, -(-max(int(max_events), 1) // ti) * ti)
     rows = pb * e * 4                     # full phenx row block
     tiles = pb * (ti + tj) * 4            # xi / xj row tiles
-    dedup = pb * (ti + tj) * e            # eq_i / eq_j bool scratch
-    pairs = pb * ti * tj * (4 + 4 + 1)    # hash, iota masks, first flags
-    hist = pb * chunk_i * tj * bt         # bucket compare slab (bool)
-    table = bt * 4                        # accumulator block
+    dedup = pb * (ti + tj) * e * 4        # eq_i / eq_j masks
+    pairs = pb * ti * tj * (4 + 4 + 1 + 4)  # hash, iota masks, flags, scratch
+    table_rows = max(8, bt // 128)
+    hist = (table_rows + 128) * 8 * tj * 2  # one-hot planes (bf16)
+    table = table_rows * 128 * (4 + 4)    # int32 table + f32 accumulator
     return int(rows + tiles + dedup + pairs + hist + table)
 
 
@@ -81,41 +90,37 @@ def mining_tile_plan(max_events: int, n_buckets_log2: int, *,
     """Pick (pb, ti, tj, bt, block_patients) for the fused kernel.
 
     Analytic mode: lane-native ``ti = tj = 128`` (matching the ops-layer
-    padding), the largest power-of-two patient block whose modeled working
-    set (:func:`fused_kernel_vmem`) fits ``vmem_bytes``, ``bt = min(2^H,
-    512)`` (seq_hist's bucket-tile width), and a counting-pass patient
-    block sized so the jnp-fallback dense planes stay under ``block_bytes``
-    at ``FUSED_BLOCK_BYTES_PER_PAIR``.
+    padding), ``pb = 8`` (one sublane tile of patients), ``bt = min(2^H,
+    2^14)``, and a counting-pass patient block sized so the jnp-fallback
+    dense planes stay under ``block_bytes`` at
+    ``FUSED_BLOCK_BYTES_PER_PAIR``.
 
     Measured mode: ``rows`` are autotune sweep records (dicts with ``pb``
-    and ``wall_s``, optionally ``ti``/``tj``/``bt``, from
+    and ``wall_s``, optionally ``ti``/``tj``, from
     ``benchmarks/mining_fused.py``); the fastest row that still fits
     ``vmem_bytes`` wins, falling back to the analytic choice when none fit.
     """
     B = 1 << n_buckets_log2
     ti = tj = 128
-    bt = min(B, 512)
+    bt = min(B, 1 << FUSED_MAX_LOG2)
     chosen = None
     source = "analytic"
     if rows:
         fitting = [r for r in rows
                    if fused_kernel_vmem(int(r["pb"]), int(r.get("ti", ti)),
-                                        int(r.get("tj", tj)),
-                                        int(r.get("bt", bt)), max_events)
+                                        int(r.get("tj", tj)), bt, max_events)
                    <= vmem_bytes]
         if fitting:
             best = min(fitting, key=lambda r: float(r["wall_s"]))
             chosen = (int(best["pb"]), int(best.get("ti", ti)),
-                      int(best.get("tj", tj)), int(best.get("bt", bt)))
+                      int(best.get("tj", tj)))
             source = "measured"
     if chosen is None:
-        pb = 1
-        for cand in (32, 16, 8, 4, 2, 1):
-            if fused_kernel_vmem(cand, ti, tj, bt, max_events) <= vmem_bytes:
-                pb = cand
-                break
-        chosen = (pb, ti, tj, bt)
-    pb, ti, tj, bt = chosen
+        # one 8-row sublane tile of patients: Mosaic unrolls the pair-tile
+        # math per patient row, and its compile time grows faster than
+        # linearly in pb (v5e compile: ~18 s at pb=8, ~66 s at pb=16)
+        chosen = (8, ti, tj)
+    pb, ti, tj = chosen
     e = max(ti, -(-max(int(max_events), 1) // ti) * ti)
     blk = max(pb, int(block_bytes // max(e * e * FUSED_BLOCK_BYTES_PER_PAIR, 1)))
     blk = min(-(-blk // pb) * pb, 4096)

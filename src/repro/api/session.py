@@ -111,7 +111,8 @@ class MiningSession:
             db, threshold=c.threshold,
             budget_bytes=c.budget_bytes or (1 << 28), codec=c.codec,
             backend=c.backend, n_buckets_log2=c.n_buckets_log2,
-            fuse_duration=c.fuse_duration, bucket_days=c.bucket_days)
+            fuse_duration=c.fuse_duration, bucket_days=c.bucket_days,
+            metrics=self.telemetry.metrics)
         return self._frame(out["seq"], out["dur"], out["patient"],
                            counts=out["counts"], vocab=db.vocab,
                            n_patients=db.n_patients)
@@ -122,7 +123,8 @@ class MiningSession:
             return self._fit_fused(db)
         mined = mining.mine(db.phenx, db.date, db.nevents, codec=c.codec,
                             fuse_duration=c.fuse_duration,
-                            bucket_days=c.bucket_days, backend=c.backend)
+                            bucket_days=c.bucket_days, backend=c.backend,
+                            metrics=self.telemetry.metrics)
         counts = (sparsity.local_bucket_counts(
             mined.seq, mined.mask, c.n_buckets_log2)
             if c.screen == "hash" else None)
@@ -138,7 +140,7 @@ class MiningSession:
             db, budget_bytes=c.budget_bytes or (1 << 28), codec=c.codec,
             backend=c.backend, n_buckets_log2=c.n_buckets_log2,
             fuse_duration=c.fuse_duration, bucket_days=c.bucket_days,
-            with_counts=c.screen == "hash")
+            with_counts=c.screen == "hash", metrics=self.telemetry.metrics)
         return self._frame(out["seq"], out["dur"], out["patient"],
                            out["mask"], counts=out.get("counts"),
                            vocab=db.vocab, n_patients=db.n_patients)
@@ -153,7 +155,8 @@ class MiningSession:
                 db, threshold=c.threshold,
                 budget_bytes=c.budget_bytes or (1 << 28), codec=c.codec,
                 backend=c.backend, n_buckets_log2=c.n_buckets_log2,
-                fuse_duration=c.fuse_duration, bucket_days=c.bucket_days)
+                fuse_duration=c.fuse_duration, bucket_days=c.bucket_days,
+                metrics=self.telemetry.metrics)
             if c.spill_dir:
                 os.makedirs(c.spill_dir, exist_ok=True)
                 np.save(os.path.join(c.spill_dir, "bucket_counts.npy"),
@@ -170,7 +173,8 @@ class MiningSession:
                 db, out_dir, budget_bytes=c.budget_bytes or (1 << 28),
                 codec=c.codec, backend=c.backend,
                 n_buckets_log2=c.n_buckets_log2,
-                fuse_duration=c.fuse_duration, bucket_days=c.bucket_days)
+                fuse_duration=c.fuse_duration, bucket_days=c.bucket_days,
+                metrics=self.telemetry.metrics)
             out = chunking.load_files(out_dir)
         finally:
             if c.spill_dir is None:   # we made the dir; don't leak a corpus

@@ -25,6 +25,7 @@ from typing import Iterable
 
 import numpy as np
 
+from repro import obs as obs_lib
 from repro.core import mining, sparsity
 from repro.data.dbmart import DBMart
 
@@ -72,7 +73,8 @@ def plan_chunks(nevents: np.ndarray, budget_bytes: int,
 def mine_chunked(db: DBMart, budget_bytes: int = 1 << 28, threshold: int | None = None,
                  codec: str = "bit", backend: str = "jnp",
                  n_buckets_log2: int = 22, fuse_duration: bool = False,
-                 bucket_days: int = 30, with_counts: bool = False) -> dict:
+                 bucket_days: int = 30, with_counts: bool = False,
+                 metrics=obs_lib.NOOP_REGISTRY) -> dict:
     """In-memory chunked mining (+ optional global hash screen).
 
     Returns flat numpy arrays {seq, dur, patient, mask} over all chunks
@@ -86,7 +88,8 @@ def mine_chunked(db: DBMart, budget_bytes: int = 1 << 28, threshold: int | None 
         sub = db.slice_patients(ch.start, ch.stop, ch.max_events)
         mined = mining.mine(sub.phenx, sub.date, sub.nevents, codec=codec,
                             fuse_duration=fuse_duration,
-                            bucket_days=bucket_days, backend=backend)
+                            bucket_days=bucket_days, backend=backend,
+                            metrics=metrics)
         if threshold is not None or with_counts:
             c = sparsity.local_bucket_counts(mined.seq, mined.mask, n_buckets_log2)
             counts = c if counts is None else sparsity.merge_bucket_counts(counts, c)
@@ -111,7 +114,7 @@ def mine_chunked(db: DBMart, budget_bytes: int = 1 << 28, threshold: int | None 
 def mine_fused(db: DBMart, threshold: int, budget_bytes: int = 1 << 28,
                codec: str = "bit", backend: str = "jnp",
                n_buckets_log2: int = 20, fuse_duration: bool = False,
-               bucket_days: int = 30) -> dict:
+               bucket_days: int = 30, metrics=obs_lib.NOOP_REGISTRY) -> dict:
     """Screen-then-materialize: corpus-free counting, survivors-only pairs.
 
     Pass 1 builds the global [2^H] bucket table with the fused mine+screen
@@ -130,17 +133,22 @@ def mine_fused(db: DBMart, threshold: int, budget_bytes: int = 1 << 28,
     counts = np.asarray(fused_ops.fused_bucket_counts(
         db.phenx, db.date, db.nevents, codec=codec,
         fuse_duration=fuse_duration, bucket_days=bucket_days,
-        n_buckets_log2=n_buckets_log2, backend=backend))
+        n_buckets_log2=n_buckets_log2, backend=backend, metrics=metrics))
     chunks = plan_chunks(np.asarray(db.nevents), budget_bytes)
     parts = []
     for ch in chunks:
         sub = db.slice_patients(ch.start, ch.stop, ch.max_events)
         mined = mining.mine(sub.phenx, sub.date, sub.nevents, codec=codec,
                             fuse_duration=fuse_duration,
-                            bucket_days=bucket_days, backend=backend)
-        seq, dur, pat, msk = mining.flatten(mined, patient_offset=ch.start)
+                            bucket_days=bucket_days, backend=backend,
+                            metrics=metrics)
+        P = mined.seq.shape[0]
+        pat = np.broadcast_to(
+            np.arange(ch.start, ch.start + P, dtype=np.int32).reshape(
+                (P,) + (1,) * (mined.seq.ndim - 1)), mined.seq.shape)
         parts.append(sparsity.screen_survivors(
-            seq, dur, pat, counts, threshold, n_buckets_log2, mask=msk))
+            mined.seq, mined.dur, pat, counts, threshold, n_buckets_log2,
+            mask=mined.mask))
     cat = lambda k, dt: (np.concatenate([p[k] for p in parts]) if parts
                          else np.zeros(0, dt))
     return {"seq": cat(0, np.int64), "dur": cat(1, np.int32),
@@ -150,7 +158,8 @@ def mine_fused(db: DBMart, threshold: int, budget_bytes: int = 1 << 28,
 def mine_to_files(db: DBMart, out_dir: str, budget_bytes: int = 1 << 28,
                   codec: str = "bit", backend: str = "jnp",
                   n_buckets_log2: int = 22, fuse_duration: bool = False,
-                  bucket_days: int = 30) -> list[str]:
+                  bucket_days: int = 30,
+                  metrics=obs_lib.NOOP_REGISTRY) -> list[str]:
     """File-based mode: one .npz per chunk + a merged bucket-count table."""
     os.makedirs(out_dir, exist_ok=True)
     for name in os.listdir(out_dir):   # stale spill from a previous cohort
@@ -163,7 +172,8 @@ def mine_to_files(db: DBMart, out_dir: str, budget_bytes: int = 1 << 28,
         sub = db.slice_patients(ch.start, ch.stop, ch.max_events)
         mined = mining.mine(sub.phenx, sub.date, sub.nevents, codec=codec,
                             fuse_duration=fuse_duration,
-                            bucket_days=bucket_days, backend=backend)
+                            bucket_days=bucket_days, backend=backend,
+                            metrics=metrics)
         c = sparsity.local_bucket_counts(mined.seq, mined.mask, n_buckets_log2)
         counts = c if counts is None else sparsity.merge_bucket_counts(counts, c)
         seq, dur, pat, msk = mining.flatten(mined, patient_offset=ch.start)

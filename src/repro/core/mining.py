@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs as obs_lib
 from repro.core import encoding
 
 
@@ -101,15 +102,20 @@ def mine_dense(
 def mine(
     phenx, date, nevents, codec: str = "bit", fuse_duration: bool = False,
     bucket_days: int = 30, backend: str = "auto", interpret: bool | None = None,
+    metrics=obs_lib.NOOP_REGISTRY,
 ) -> Mined:
     """Mine transitive sequences.  backend: 'kernel' | 'jnp' | 'auto'.
 
     'kernel' uses the Pallas pair-generation kernel (dense layout);
     'jnp' the packed-triangular reference.  'auto' uses the kernel on TPU
-    and the reference elsewhere.
+    and the reference elsewhere.  The implementation that ran is counted
+    on ``metrics`` (``kernel.dispatch{op=pairgen}``).
     """
     if backend == "auto":
         backend = "kernel" if jax.default_backend() == "tpu" else "jnp"
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    obs_lib.count_dispatch(metrics, "pairgen", backend == "kernel", interpret)
     if backend == "kernel":
         from repro.kernels.tspm_pairgen import ops as pairgen_ops
 

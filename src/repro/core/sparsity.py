@@ -140,7 +140,7 @@ def local_bucket_counts(seq, mask, n_buckets_log2: int):
     mask = jnp.asarray(mask, bool)
     P = seq.shape[0]
     flat = jnp.where(mask, seq, SENTINEL).reshape(P, -1)
-    srt = jnp.sort(flat, axis=1)
+    srt = jnp.sort(flat, axis=1, stable=False)   # keys only: same result
     first = row_first_flags(srt)
     h = hash_bucket(srt, n_buckets_log2)
     counts = jnp.zeros(1 << n_buckets_log2, jnp.int32)
@@ -189,13 +189,13 @@ def screen_survivors(seq, dur, patient, counts, threshold,
     compacted arrays are byte-identical to screening the materialized
     corpus with the same table.
     """
-    seq = jnp.asarray(seq, jnp.int64).reshape(-1)
-    if mask is None:
-        mask = seq != SENTINEL
-    else:
-        mask = jnp.asarray(mask, bool).reshape(-1)
+    # the screen runs in the caller's layout and only the host flattens:
+    # an eager TPU reshape of a [P, E, E] bool plane can take minutes to
+    # compile (v5e, P=256, E=536)
+    seq = jnp.asarray(seq, jnp.int64)
+    mask = seq != SENTINEL if mask is None else jnp.asarray(mask, bool)
     keep = np.asarray(screen_hash_from_counts(
-        seq, mask, jnp.asarray(counts), threshold, n_buckets_log2))
-    return (np.asarray(seq)[keep],
-            np.asarray(jnp.asarray(dur, jnp.int32).reshape(-1))[keep],
-            np.asarray(jnp.asarray(patient, jnp.int32).reshape(-1))[keep])
+        seq, mask, jnp.asarray(counts), threshold, n_buckets_log2)).reshape(-1)
+    return (np.asarray(seq).reshape(-1)[keep],
+            np.asarray(dur, np.int32).reshape(-1)[keep],
+            np.asarray(patient, np.int32).reshape(-1)[keep])

@@ -17,7 +17,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
+from repro import obs as obs_lib
 
 _RULES: contextvars.ContextVar = contextvars.ContextVar("axis_rules",
                                                         default=None)
@@ -114,7 +114,8 @@ def _device_resident_stack(tables, mesh, axis: str):
         NamedSharding(mesh, P(axis)), parts)
 
 
-def merge_sharded_counts(tables, mesh=None, axis: str = "data"):
+def merge_sharded_counts(tables, mesh=None, axis: str = "data",
+                         metrics=obs_lib.NOOP_REGISTRY):
     """Global screen table from per-shard bucket-count tables: one psum.
 
     Per-shard sketch tables count distinct (patient, sequence) pairs over
@@ -127,18 +128,24 @@ def merge_sharded_counts(tables, mesh=None, axis: str = "data"):
     pinned one-per-mesh-device (``ShardedStreamService`` with
     ``placement='devices'``) are stacked in place; any other committed
     layout gathers through the host first — ``jnp.stack`` cannot mix
-    device commitments.
+    device commitments.  The path taken is counted on ``metrics``
+    (``shard.merge{path=device_stack|host_gather|stacked|local}``).
     """
     tables = [jnp.asarray(t) for t in tables]
     if mesh is not None:
         resident = _device_resident_stack(tables, mesh, axis)
         if resident is not None:
+            metrics.counter("shard.merge", path="device_stack").inc()
             return _jitted_merge(mesh, axis)(resident)
-    if len({d for t in tables for d in t.devices()}) > 1:
+    gather = len({d for t in tables for d in t.devices()}) > 1
+    if gather:
         tables = [np.asarray(t) for t in tables]
     stacked = jnp.stack(tables)
     if mesh is None:
-        return stacked.sum(axis=0)
+        metrics.counter("shard.merge", path="local").inc()
+        return stacked.sum(axis=0, dtype=stacked.dtype)
+    metrics.counter("shard.merge",
+                    path="host_gather" if gather else "stacked").inc()
     n = mesh.shape[axis]
     if stacked.shape[0] % n:   # pad with zero tables to a shardable count
         pad = n - stacked.shape[0] % n
@@ -150,10 +157,10 @@ def merge_sharded_counts(tables, mesh=None, axis: str = "data"):
 
 @functools.lru_cache(maxsize=8)
 def _jitted_merge(mesh, axis: str):
-    # jit'd once per (mesh, axis): eager shard_map re-traces every call on
-    # jax 0.4.x, and the merge runs on every snapshot rebuild
-    return jax.jit(compat.shard_map(
-        lambda c: jax.lax.psum(c.sum(axis=0), axis), mesh=mesh,
+    # jit'd once per (mesh, axis): the merge runs on every snapshot rebuild
+    return jax.jit(jax.shard_map(
+        lambda c: jax.lax.psum(c.sum(axis=0, dtype=c.dtype), axis),
+        mesh=mesh,
         in_specs=P(axis), out_specs=P()))
 
 

@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.util import int32_trace
+
 
 def _delta_kernel(nold_ref, nnew_ref, xi_ref, di_ref, xj_ref, dj_ref,
                   s_ref, e_ref, dur_ref, msk_ref, *, ti: int, tj: int):
@@ -49,6 +51,7 @@ def _delta_kernel(nold_ref, nnew_ref, xi_ref, di_ref, xj_ref, dj_ref,
     msk_ref[:] = mask
 
 
+@int32_trace
 @functools.partial(jax.jit, static_argnames=("pb", "ti", "tj", "interpret"))
 def delta_planes(phenx, date, n_old, n_new, new_phenx, new_date,
                  pb: int = 8, ti: int = 128, tj: int = 128,

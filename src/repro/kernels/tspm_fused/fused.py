@@ -6,9 +6,8 @@ screen table (``sparsity.local_bucket_counts``).  This kernel produces the
 *same table* without ever writing a pair: each Pb x Ti x Tj tile (the
 tiling shared with tspm_pairgen / tspm_delta) decides in-register which of
 its pairs is the patient's first contribution of that (start, end) value
-pair, hashes those, and compare-and-reduces them into a VMEM-resident
-bucket-tile accumulator (the seq_hist histogram idiom — TPU has no vector
-scatter).
+pair, hashes those, and counts them into a VMEM-resident bucket table with
+one-hot matmuls on the MXU (TPU has no vector scatter).
 
 Dedup without the row sort: pair (i, j) is its patient's first occurrence
 of the value pair (x_i, x_j) iff
@@ -36,13 +35,18 @@ constants into five, partial products < 2^26 and column sums < 2^29 stay
 int32-exact, one carry propagation, then the top H bits are stitched from
 the limbs (H <= 24 keeps every stitch shift in-range).
 
-Grid: (bucket-tiles, patient-blocks, i-tiles, j-tiles) with bucket tiles
-OUTERMOST so each [1, bt] accumulator block sees all its writes
-consecutively (the Pallas revisiting rule, as in seq_hist — there rows
-are innermost for the same reason).  The cost is recomputing
-mask/dedup/hash once per bucket tile; with bt = min(2^H, 512) that factor
-is 2^H / 512, bounded by the compare-and-reduce regime this kernel is
-dispatched in (ops.KERNEL_MAX_LOG2).
+Histogram: the [2^H] table lives in VMEM as [rows, 128] (bucket h at
+row h >> 7, lane h & 127).  For each row of the pair tile, a bf16 one-hot
+of h >> 7 ([rows, Tj]) times the transposed one-hot of h & 127
+([128, Tj]) adds every pair to its cell; dead pairs carry h = -1 and
+match no row.  Pairs sit on lanes in both operands, so Mosaic needs no
+lane-to-sublane relayout, and the MXU does the O(pairs x 2^H) work the
+compare-and-reduce form would put on the VPU.  Eight rows are stacked
+per matmul (K = 8 Tj).
+
+Grid: (patient-blocks, i-tiles, j-tiles), every step accumulating into
+the one table block.  The kernel body is traced with x64 off
+(``kernels.util.int32_trace``): every literal and reduction is int32.
 """
 from __future__ import annotations
 
@@ -51,14 +55,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import encoding, sparsity
+from repro.kernels.util import int32_trace
 
 LIMB_BITS = 13
 LIMB_MASK = (1 << LIMB_BITS) - 1
 N_LIMBS = 5                       # 4 * 13 + 12 = 64 bits
 _M64 = (1 << 64) - 1
 MAX_BUCKETS_LOG2 = 24             # stitch shifts stay < 13 bits for H <= 24
+LANE_BITS = 7
+TABLE_LANES = 1 << LANE_BITS      # bucket table laid out [rows, 128]
 
 
 def _limbs(c: int) -> tuple[int, ...]:
@@ -131,12 +139,16 @@ def hash_parts(start, end, bucket=None, *, codec: str = "bit",
     return jnp.asarray(h & ((1 << H) - 1), jnp.int32)
 
 
-def _fused_kernel(nev_ref, xi_ref, xj_ref, xr_ref, out_ref, *, ti: int,
-                  tj: int, bt: int, chunk_i: int, codec: str,
-                  n_buckets_log2: int):
-    b = pl.program_id(0)
-    pi = pl.program_id(2)
-    pj = pl.program_id(3)
+def table_rows(n_buckets_log2: int) -> int:
+    """Rows of the kernel's [rows, 128] bucket table: bucket ``h`` sits at
+    (h >> 7, h & 127); at least 8 rows keep the block sublane-aligned."""
+    return max(8, (1 << n_buckets_log2) // TABLE_LANES)
+
+
+def _fused_kernel(nev_ref, xi_ref, xj_ref, xr_ref, out_ref, h_scr, *,
+                  ti: int, tj: int, codec: str, n_buckets_log2: int):
+    pi = pl.program_id(1)
+    pj = pl.program_id(2)
     gi = pi * ti + jax.lax.broadcasted_iota(jnp.int32, (1, ti, 1), 1)
     gj = pj * tj + jax.lax.broadcasted_iota(jnp.int32, (1, 1, tj), 2)
     nev = nev_ref[:]                                    # [Pb, 1]
@@ -151,7 +163,8 @@ def _fused_kernel(nev_ref, xi_ref, xj_ref, xr_ref, out_ref, *, ti: int,
     # lookbacks stay on real events: k < gi < nevents for any valid pair,
     # so padded positions are never consulted
     eq_i = (xr[:, None, :] == xi[:, :, None]) & (k < gi)       # [Pb, Ti, E]
-    first_start = ~jnp.any(eq_i, axis=2)                       # [Pb, Ti]
+    # int32 reductions: Mosaic reduces no bool vectors
+    first_start = jnp.max(eq_i.astype(jnp.int32), axis=2) == 0  # [Pb, Ti]
     gj_col = pj * tj + jax.lax.broadcasted_iota(jnp.int32, (1, tj, 1), 1)
     eq_j = (xr[:, None, :] == xj[:, :, None]) & (k < gj_col)   # [Pb, Tj, E]
     prev_end = jnp.max(jnp.where(eq_j, k, -1), axis=2)         # [Pb, Tj]
@@ -159,60 +172,78 @@ def _fused_kernel(nev_ref, xi_ref, xj_ref, xr_ref, out_ref, *, ti: int,
     first = valid & first_start[:, :, None] & (prev_end[:, None, :] <= gi)
     h = hash_parts(xi[:, :, None], xj[:, None, :], codec=codec,
                    n_buckets_log2=n_buckets_log2)
-    h = jnp.where(first, h, -1)          # dead pairs match no bucket
+    h = jnp.where(first, h, -1)          # dead pairs match no table row
+    pb = h.shape[0]
+    for p in range(pb):
+        h_scr[p * ti:(p + 1) * ti, :] = h[p]
 
-    buckets = b * bt + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bt), 2)
+    # histogram on the MXU: for a row of pairs, one-hot(h >> 7) [rows, Tj]
+    # times one-hot(h & 127)^T [Tj, 128] counts every pair into its
+    # (row, lane) cell of the table.  The one-hots are exact in bf16 and a
+    # grid step adds at most Pb*Ti*Tj < 2^24 to a cell, so the f32
+    # accumulator is exact too.
+    rows = out_ref.shape[0]
+    row_id = jax.lax.broadcasted_iota(jnp.int32, (rows, tj), 0)
+    lane_id = jax.lax.broadcasted_iota(jnp.int32, (TABLE_LANES, tj), 0)
 
-    def body(c, acc):
-        h_c = jax.lax.dynamic_slice_in_dim(h, c * chunk_i, chunk_i, axis=1)
-        h_c = h_c.reshape(h.shape[0], chunk_i * tj)
-        # dtype= pins the accumulator: with x64 enabled jnp.sum promotes
-        # int32 to int64, which the int32 out_ref swap rejects (seq_hist)
-        return acc + jnp.sum((h_c[:, :, None] == buckets).astype(jnp.int32),
-                             axis=(0, 1), dtype=jnp.int32)
+    def body(g, acc):
+        blk = h_scr[pl.ds(pl.multiple_of(g * 8, 8), 8), :]    # [8, Tj]
+        hot_r, hot_l = [], []
+        for r in range(8):
+            hr = blk[r:r + 1, :]
+            hot_r.append((hr >> LANE_BITS == row_id).astype(jnp.bfloat16))
+            hot_l.append(((hr & (TABLE_LANES - 1)) == lane_id)
+                         .astype(jnp.bfloat16))
+        a = jnp.concatenate(hot_r, axis=1)                     # [rows, 8Tj]
+        b = jnp.concatenate(hot_l, axis=1)                     # [128, 8Tj]
+        return acc + jax.lax.dot_general(
+            a, b, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     partial = jax.lax.fori_loop(
-        0, ti // chunk_i, body, jnp.zeros((bt,), jnp.int32))
+        0, pb * ti // 8, body, jnp.zeros((rows, TABLE_LANES), jnp.float32))
 
-    @pl.when((pl.program_id(1) == 0) & (pi == 0) & (pj == 0))
+    @pl.when((pl.program_id(0) == 0) & (pi == 0) & (pj == 0))
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    out_ref[:] += partial[None, :]
+    out_ref[:] += partial.astype(jnp.int32)
 
 
+@int32_trace
 @functools.partial(jax.jit, static_argnames=(
-    "n_buckets_log2", "codec", "pb", "ti", "tj", "bt", "chunk_i", "interpret"))
+    "n_buckets_log2", "codec", "pb", "ti", "tj", "interpret"))
 def fused_table(phenx, nevents, n_buckets_log2: int, codec: str = "bit",
-                pb: int = 8, ti: int = 128, tj: int = 128, bt: int = 512,
-                chunk_i: int = 4, interpret: bool = False):
+                pb: int = 8, ti: int = 128, tj: int = 128,
+                interpret: bool = False):
     """[2^H] int32 bucket counts of a padded [P, E] cohort (== the table
     ``sparsity.local_bucket_counts`` builds from the materialized corpus).
 
-    P must divide by pb, E by ti == tj, 2^H by bt, ti by chunk_i
-    (ops.py pads and clamps).
+    P must divide by pb and E by ti == tj (ops.py pads).
     """
     P, E = phenx.shape
     B = 1 << n_buckets_log2
     assert P % pb == 0 and E % ti == 0 and E % tj == 0, (P, E, pb, ti, tj)
-    assert B % bt == 0 and ti % chunk_i == 0, (B, bt, ti, chunk_i)
-    grid = (B // bt, P // pb, E // ti, E // tj)
+    assert ti % 8 == 0, ti
+    rows = table_rows(n_buckets_log2)
+    grid = (P // pb, E // ti, E // tj)
     nev2 = nevents.reshape(P, 1).astype(jnp.int32)
     x = phenx.astype(jnp.int32)
     kernel = functools.partial(
-        _fused_kernel, ti=ti, tj=tj, bt=bt, chunk_i=chunk_i, codec=codec,
+        _fused_kernel, ti=ti, tj=tj, codec=codec,
         n_buckets_log2=n_buckets_log2)
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((pb, 1), lambda b, p, i, j: (p, 0)),   # nevents
-            pl.BlockSpec((pb, ti), lambda b, p, i, j: (p, i)),  # phenx_i
-            pl.BlockSpec((pb, tj), lambda b, p, i, j: (p, j)),  # phenx_j
-            pl.BlockSpec((pb, E), lambda b, p, i, j: (p, 0)),   # full row
+            pl.BlockSpec((pb, 1), lambda p, i, j: (p, 0)),   # nevents
+            pl.BlockSpec((pb, ti), lambda p, i, j: (p, i)),  # phenx_i
+            pl.BlockSpec((pb, tj), lambda p, i, j: (p, j)),  # phenx_j
+            pl.BlockSpec((pb, E), lambda p, i, j: (p, 0)),   # full row
         ],
-        out_specs=pl.BlockSpec((1, bt), lambda b, p, i, j: (0, b)),
-        out_shape=jax.ShapeDtypeStruct((1, B), jnp.int32),
+        out_specs=pl.BlockSpec((rows, TABLE_LANES), lambda p, i, j: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, TABLE_LANES), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((pb * ti, tj), jnp.int32)],
         interpret=interpret,
     )(nev2, x, x, x)
-    return out[0]
+    return out.reshape(-1)[:B]

@@ -1,7 +1,7 @@
 """Dispatching wrapper: pad -> fused Pallas counting -> [2^H] bucket table.
 
-Mirrors tspm_pairgen/ops.py (padding recipe, interpret default) and
-seq_hist/ops.py (compare-and-reduce regime bound).  Tile sizes come from
+Mirrors tspm_pairgen/ops.py (padding recipe, interpret default).  Tile
+sizes come from
 ``analysis.roofline.mining_tile_plan`` — analytic VMEM fit by default,
 measured autotune rows when ``benchmarks/mining_fused.py`` hands them in.
 """
@@ -11,34 +11,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs as obs_lib
 from repro.analysis import roofline
 from repro.kernels.tspm_fused import fused as _k
 from repro.kernels.tspm_fused import ref as _ref
 from repro.kernels.util import pad_to as _pad_to
 
-# compare-and-reduce histogram work is O(pairs * 2^H): past ~2^14 buckets
-# the recompute-per-bucket-tile factor loses to the jnp block fallback
-# (same bound as seq_hist's scatter-add crossover)
-KERNEL_MAX_LOG2 = 14
+KERNEL_MAX_LOG2 = roofline.FUSED_MAX_LOG2
 
 
-def _kernel_block(phenx, nevents, codec, n_buckets_log2, plan, pb, tile, bt,
+def _kernel_block(phenx, nevents, codec, n_buckets_log2, plan, pb, tile,
                   interpret):
     P, E = phenx.shape
     tile = int(tile or plan.ti)
-    pb = int(pb or plan.pb)
-    bt_ = min(int(bt or plan.bt), 1 << n_buckets_log2)
-    while (1 << n_buckets_log2) % bt_:
-        bt_ //= 2
+    pb = min(int(pb or plan.pb), P)
     t = min(tile, max(128, 1 << int(np.ceil(np.log2(max(E, 1))))))
-    t = min(t, tile)
     x = _pad_to(phenx, t, 1)
-    pbb = min(pb, P) if P % min(pb, P) == 0 else 1
-    x = _pad_to(x, pbb, 0)
-    nev = _pad_to(nevents, pbb, 0)     # padded patients: nevents == 0
+    x = _pad_to(x, pb, 0)
+    nev = _pad_to(nevents, pb, 0)      # padded patients: nevents == 0
     return _k.fused_table(
-        x, nev, n_buckets_log2=n_buckets_log2, codec=codec, pb=pbb, ti=t,
-        tj=t, bt=bt_, chunk_i=min(4, t), interpret=interpret)
+        x, nev, n_buckets_log2=n_buckets_log2, codec=codec, pb=pb, ti=t,
+        tj=t, interpret=interpret)
 
 
 def fused_bucket_counts(phenx, date, nevents, codec: str = "bit",
@@ -46,8 +39,8 @@ def fused_bucket_counts(phenx, date, nevents, codec: str = "bit",
                         n_buckets_log2: int = 20, backend: str = "auto",
                         block_patients: int | None = None,
                         pb: int | None = None, tile: int | None = None,
-                        bt: int | None = None,
-                        interpret: bool | None = None):
+                        interpret: bool | None = None,
+                        metrics=obs_lib.NOOP_REGISTRY):
     """Corpus-free [2^H] bucket counts == local_bucket_counts(mine(...)).
 
     backend: 'kernel' | 'jnp' | 'auto' ('auto' = kernel on TPU, jnp ref
@@ -55,7 +48,8 @@ def fused_bucket_counts(phenx, date, nevents, codec: str = "bit",
     H <= KERNEL_MAX_LOG2; fused-duration ids (whose cross-row dedup does
     not decompose over tiles) and larger tables take the blocked jnp
     reference — still corpus-free at cohort level (peak is one
-    [block, E, E] slab, never [P, E, E]).
+    [block, E, E] slab, never [P, E, E]).  Which of the two ran is counted
+    on ``metrics`` (``kernel.dispatch{op=fused}``, see ``obs.dispatch``).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -74,12 +68,13 @@ def fused_bucket_counts(phenx, date, nevents, codec: str = "bit",
     blk = int(block_patients or plan.block_patients)
     use_kernel = (backend == "kernel" and not fuse_duration
                   and H <= KERNEL_MAX_LOG2)
+    obs_lib.count_dispatch(metrics, "fused", use_kernel, interpret)
     counts = jnp.zeros(1 << H, jnp.int32)
     for s in range(0, P, blk):
         e = s + blk
         if use_kernel:
             part = _kernel_block(phenx[s:e], nevents[s:e], codec, H, plan,
-                                 pb, tile, bt, interpret)
+                                 pb, tile, interpret)
         else:
             part = _ref.block_bucket_counts(
                 phenx[s:e], date[s:e], nevents[s:e], codec, fuse_duration,

@@ -22,10 +22,10 @@ def pairgen(phenx, date, nevents, codec: str = "bit",
     nevents = jnp.asarray(nevents, jnp.int32)
     P, E = phenx.shape
     t = min(tile, max(128, 1 << int(np.ceil(np.log2(max(E, 1))))))
-    t = min(t, tile)
     phenx_p = _pad_to(phenx, t, 1)
     date_p = _pad_to(date, t, 1)
-    pbb = min(pb, P) if P % min(pb, P) == 0 else 1
+    # padded patients have nevents == 0, so none of their pairs is valid
+    pbb = min(pb, P)
     phenx_p = _pad_to(phenx_p, pbb, 0)
     date_p = _pad_to(date_p, pbb, 0)
     nev_p = _pad_to(nevents, pbb, 0)

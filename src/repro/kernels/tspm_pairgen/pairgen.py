@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.util import int32_trace
+
 
 def _pairgen_kernel(nev_ref, xi_ref, di_ref, xj_ref, dj_ref,
                     s_ref, e_ref, dur_ref, msk_ref, *, ti: int, tj: int):
@@ -44,6 +46,7 @@ def _pairgen_kernel(nev_ref, xi_ref, di_ref, xj_ref, dj_ref,
     msk_ref[:] = mask
 
 
+@int32_trace
 @functools.partial(jax.jit, static_argnames=("pb", "ti", "tj", "interpret"))
 def pairgen_planes(phenx, date, nevents, pb: int = 8, ti: int = 128,
                    tj: int = 128, interpret: bool = False):

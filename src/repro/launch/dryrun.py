@@ -23,7 +23,6 @@ import traceback  # noqa: E402
 import jax        # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from repro import compat                                     # noqa: E402
 from repro.analysis import costmodel                         # noqa: E402
 from repro.analysis import roofline as rl                    # noqa: E402
 from repro.configs import ARCHS, get_config                  # noqa: E402
@@ -156,7 +155,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis() or {}
         hlo = compiled.as_text()
         coll = rl.collective_bytes(hlo)   # per-device, trip-scaled (exact)
         chips = mesh.devices.size

@@ -129,6 +129,9 @@ def main(argv=None):
     ap.add_argument("--clients", type=int, default=32)
     ap.add_argument("--queries", type=int, default=128)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.workload == "queries":
         if args.batch == 4:     # lm default is too small for query waves
             args.batch = 32

@@ -23,6 +23,7 @@ import time
 import numpy as np
 
 from repro.api import MiningConfig, MiningSession
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data import dbmart, synthea
 from repro.stream.shard import ShardedStreamService, ShardRouter
 
@@ -56,12 +57,16 @@ def replay_waves(db, svc, n_waves: int, seed: int = 0, start_wave: int = 0):
 def state_digest(svc) -> str:
     """One hex digest over everything the journal replay must reproduce
     (corpus, sketch table, pid table) — the cross-process comparison key
-    for the replay drill."""
+    for the replay drill.  Corpus rows are hashed in canonical (seq,
+    patient, dur) order, so a sharded service and a one-shard service that
+    mined the same cohort give the same digest."""
     snap = svc.snapshot()
+    seq, dur, pat = (np.asarray(getattr(snap, k))
+                     for k in ("seq", "dur", "patient"))
+    order = np.lexsort((dur, pat, seq))
     h = hashlib.sha256()
-    for name in ("seq", "dur", "patient", "counts"):
-        h.update(np.ascontiguousarray(
-            np.asarray(getattr(snap, name))).tobytes())
+    for arr in (seq[order], dur[order], pat[order], np.asarray(snap.counts)):
+        h.update(np.ascontiguousarray(arr).tobytes())
     pids = svc.pids if hasattr(svc, "shards") else svc.store.pids
     h.update(repr(sorted((str(k), int(v))
                          for k, v in dict(pids).items())).encode())
@@ -140,6 +145,7 @@ def main(argv=None):
                          "config) and print its state digest")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.replay_journal:
         t0 = time.perf_counter()
         session = MiningSession.replay(args.replay_journal)

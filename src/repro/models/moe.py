@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.distributed.sharding import current_rules
 from repro.models import layers
 from repro.models.layers import truncnorm
@@ -122,7 +121,7 @@ def apply_shard_map(p, x, cfg):
 
     from jax.sharding import PartitionSpec as P
 
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(ba, None, None), P(None, None),
                   P(ma, None, None), P(ma, None, None), P(ma, None, None)),

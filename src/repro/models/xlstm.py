@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.distributed.sharding import constrain, current_rules, fsdp_axis_for
 from repro.models import layers, ssm_common
 from repro.models.layers import linear, linear_init, rmsnorm
@@ -173,7 +172,7 @@ def slstm_apply(p, x, cfg, state=None):
         # check_vma=False: with VMA tracking on, the replicated-weight
         # cotangent is converted varying->invariant (psum) at every scan
         # step; classic semantics psums once at the shard_map exit.
-        new_state, hs = compat.shard_map(
+        new_state, hs = jax.shard_map(
             _slstm_scan, mesh=mesh,
             in_specs=(bspec(5), P(None, None, None, None), state_specs),
             out_specs=(state_specs, bspec(4, batch_dim=1)),

@@ -10,6 +10,9 @@ the rebalancer, and CI gates) read instead:
   * ``trace``   — begin/finish span trees with per-shard tracks,
     exported as JSON or Chrome-trace format (chrome://tracing,
     Perfetto), optionally bridged to ``jax.profiler.TraceAnnotation``;
+  * ``count_dispatch`` — the ``kernel.dispatch`` counter every
+    kernel-or-reference dispatch (pairgen, delta, fused) increments with
+    the implementation it ran, so no fallback to the reference is silent;
   * ``telemetry`` — the per-session bundle of both, plus the
     :class:`RetraceTracker` that turns jax's compiled-variant counts
     into a per-tick ``jit.retraces`` counter (the O(log) recompile
@@ -21,7 +24,7 @@ never changes what is mined, byte for byte, on or off
 """
 from repro.obs.metrics import (Counter, Gauge, Histogram,  # noqa: F401
                                MetricsRegistry, NOOP_METRIC, NOOP_REGISTRY,
-                               NoopRegistry)
+                               NoopRegistry, count_dispatch)
 from repro.obs.telemetry import (NOOP, RetraceTracker,  # noqa: F401
                                  Telemetry, default_hot_functions,
                                  jit_cache_size)

@@ -169,6 +169,19 @@ class MetricsRegistry:
                 m.value = 0
 
 
+def count_dispatch(metrics, op: str, kernel: bool,
+                   interpret: bool | None, labels: dict | None = None) -> None:
+    """Record which implementation one kernel-or-reference dispatch ran:
+    ``kernel.dispatch{op=<op>,impl=kernel|jnp,interpret=True|False}``,
+    plus the caller's ``labels`` (a stream shard's ``shard=``).
+    ``interpret`` is only ever True for a Pallas kernel run by the
+    interpreter (off-TPU); the jnp reference reports False."""
+    metrics.counter("kernel.dispatch", op=op,
+                    impl="kernel" if kernel else "jnp",
+                    interpret=bool(kernel and interpret),
+                    **(labels or {})).inc()
+
+
 class _NoopMetric:
     """Shared do-nothing Counter/Gauge/Histogram stand-in."""
 

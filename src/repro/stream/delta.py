@@ -19,6 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs as obs_lib
 from repro.core import encoding
 from repro.core.mining import Mined
 from repro.kernels.tspm_delta.ref import delta_planes_ref
@@ -43,10 +44,17 @@ def delta_mine(
     phenx, date, n_old, n_new, new_phenx, new_date, codec: str = "bit",
     fuse_duration: bool = False, bucket_days: int = 30,
     backend: str = "auto", interpret: bool | None = None,
+    metrics=obs_lib.NOOP_REGISTRY, labels: dict | None = None,
 ) -> Mined:
-    """Mine the new-pair slab.  backend: 'kernel' | 'jnp' | 'auto'."""
+    """Mine the new-pair slab.  backend: 'kernel' | 'jnp' | 'auto'.  The
+    implementation that ran is counted on ``metrics``
+    (``kernel.dispatch{op=delta}`` plus ``labels``)."""
     if backend == "auto":
         backend = "kernel" if jax.default_backend() == "tpu" else "jnp"
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    obs_lib.count_dispatch(metrics, "delta", backend == "kernel", interpret,
+                           labels)
     if backend == "kernel":
         from repro.kernels.tspm_delta import ops as delta_ops
 

@@ -321,7 +321,8 @@ class StreamService(SnapshotQueries):
             self.store.phenx[rows, :Ew], self.store.date[rows, :Ew],
             n_old, n_new, new_phenx, new_date, codec=self.codec,
             fuse_duration=self.fuse_duration, bucket_days=self.bucket_days,
-            backend=self.backend, interpret=self.interpret)
+            backend=self.backend, interpret=self.interpret,
+            metrics=self.obs.metrics, labels=self._labels)
         sketch_pending = self.sketch.update_begin(pids, mined.seq, mined.mask)
         t_disp = time.perf_counter()
         self.obs.tracer.finish(sp, patients=B, events=int(n_new.sum()))

@@ -53,6 +53,7 @@ from __future__ import annotations
 import time
 import zlib
 from collections import deque
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -329,23 +330,29 @@ class ShardedStreamService(SnapshotQueries):
         queues drained (and no migration state left in flight).
 
         ``'devices'`` placement dispatches every shard's wave before
-        collecting any (each device mines while the host assembles the
-        next shard's wave); ``'host'`` keeps the serial per-shard tick.
-        Pending migration admits land here, at the tick boundary: shards
-        with no admit dispatch first, so a destination's restore overlaps
-        their mining instead of delaying it."""
+        collecting any, one dispatch thread per shard: each device mines
+        while the host assembles the other shards' waves, and a tick shape
+        new to the devices compiles for all of them at once (XLA compiles
+        per device and releases the GIL; dispatched one shard after
+        another, a cold 4-chip replay compiled every shape 4 times in a
+        row).  ``'host'`` keeps the serial per-shard tick.  Pending
+        migration admits land here, at the tick boundary: shards with no
+        admit dispatch first, so a destination's restore overlaps their
+        mining instead of delaying it."""
         order = sorted(range(self.n_shards),
                        key=lambda s: bool(self._pending_admits[s]))
         sp = self.obs.tracer.begin("sharded.tick", cat="host")
         if self.placement == "devices":
-            begun = []
-            for s in order:
-                self._flush_pending(s)
-                svc = self.shards[s]
-                if svc.queue:
-                    p = svc.tick_begin()
-                    if p is not None:
-                        begun.append((s, svc, p))
+            dispatched = []
+            with ThreadPoolExecutor(self.n_shards) as pool:
+                for s in order:
+                    self._flush_pending(s)
+                    if self.shards[s].queue:
+                        dispatched.append(
+                            (s, pool.submit(self.shards[s].tick_begin)))
+                begun = [(s, self.shards[s], f.result())
+                         for s, f in dispatched]
+            begun = [(s, svc, p) for s, svc, p in begun if p is not None]
             out = []
             for s, svc, p in begun:
                 st = svc.tick_finish(p)
@@ -670,7 +677,8 @@ class ShardedStreamService(SnapshotQueries):
         self._flush_pending()   # an in-flight patient's ids are subtracted
         if self._gcounts is None:
             self._gcounts = np.asarray(merge_sharded_counts(
-                [svc.sketch.counts for svc in self.shards], self.mesh))
+                [svc.sketch.counts for svc in self.shards], self.mesh,
+                metrics=self.obs.metrics))
         return self._gcounts
 
     def snapshot(self) -> Snapshot:

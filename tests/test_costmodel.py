@@ -8,7 +8,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import compat
 from repro.analysis import costmodel
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
@@ -26,7 +25,8 @@ def unrolled():
 
 
 def _hlo_flops(fn, *args):
-    return compat.hlo_flops(jax.jit(fn).lower(*args))
+    cost = jax.jit(fn).lower(*args).compile().cost_analysis() or {}
+    return float(cost.get("flops", 0.0))
 
 
 FAMILIES = ["tspm-mlho", "gemma2-2b", "deepseek-moe-16b", "xlstm-125m",

@@ -63,7 +63,7 @@ def test_sharded_hash_screen_matches_global():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from functools import partial
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import mining, sparsity
 from repro.data import synthea, dbmart
 
@@ -95,7 +95,7 @@ def test_compressed_psum_convergence():
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.distributed.compression import compressed_psum_mean
 
 mesh = jax.make_mesh((8,), ("pod",))
@@ -115,8 +115,7 @@ def step(w, Xs, ys, err):
     g_mean, new_err = compressed_psum_mean(g, "pod", err[0])
     return g_mean, new_err[None]  # error feedback stays shard-local
 
-# jit the shard_map'd step: eager shard_map re-traces every call on
-# jax 0.4.x, which turns 300 iterations into minutes
+# jit the shard_map'd step once: the loop below runs it 300 times
 step = jax.jit(step)
 w = jnp.zeros(16)
 err = jax.device_put(jnp.zeros((8, 16)), NamedSharding(mesh, P("pod")))
@@ -125,6 +124,10 @@ yd = jax.device_put(y, NamedSharding(mesh, P("pod")))
 for i in range(300):
     g, err = step(w, Xd, yd, err)
     w = w - 0.1 * g
+    # one step in flight at a time: queued work of the next step can hold
+    # the CPU pool threads that the 8 all-reduce participants need, and
+    # on a loaded host the rendezvous then aborts after 40 s
+    w.block_until_ready()
 final = float(jnp.mean((X @ w - y) ** 2))
 assert final < 1e-3, final
 print("COMPRESS-OK", final)

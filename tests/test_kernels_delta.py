@@ -189,12 +189,3 @@ def test_count_delta_pairs_closed_form():
                                        new_ph, new_dt)
     assert int(stream_delta.count_delta_pairs(n_old, n_new)) \
         == int(np.asarray(slab.mask).sum())
-
-
-def test_delta_kernel_is_lowerable_for_tpu_style_blocks():
-    import jax
-
-    db = random_dbmart(np.random.default_rng(4), n_patients=8, max_events=100)
-    n_old, n_new, new_ph, new_dt = split_delta(db)
-    fn = lambda *a: ops.delta_pairgen(*a, interpret=True)
-    jax.jit(fn).lower(db.phenx, db.date, n_old, n_new, new_ph, new_dt)

@@ -226,13 +226,22 @@ def test_hash_adversary_single_bucket(backend):
 
 def test_kernel_dispatch_regime():
     """backend='kernel' falls back to the jnp block path past
-    KERNEL_MAX_LOG2 and for fused ids — and stays exact there."""
+    KERNEL_MAX_LOG2 and for fused ids — stays exact there, and says so on
+    the kernel.dispatch counter."""
+    from repro import obs
     rng = np.random.default_rng(41)
     db = random_dbmart(rng, n_patients=5, max_events=10)
     H = ops.KERNEL_MAX_LOG2 + 1
+    reg = obs.MetricsRegistry()
     want = oracle_counts(db, n_buckets_log2=H)
-    got = fused_counts(db, "kernel", n_buckets_log2=H)
+    got = fused_counts(db, "kernel", n_buckets_log2=H, metrics=reg)
     np.testing.assert_array_equal(got, want)
+    assert reg.snapshot() == {
+        "kernel.dispatch{impl=jnp,interpret=False,op=fused}": 1}
+    fused_counts(db, "kernel", n_buckets_log2=H - 1, metrics=reg,
+                 interpret=True)
+    assert reg.value("kernel.dispatch", op="fused", impl="kernel",
+                     interpret=True) == 1
 
 
 # --- roofline tile selection -------------------------------------------------

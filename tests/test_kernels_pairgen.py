@@ -55,12 +55,3 @@ def test_planes_ref_matches_planes_kernel():
     assert (np.asarray(s) == np.asarray(sr)).all()
     assert (np.asarray(e) == np.asarray(er)).all()
     assert (np.asarray(d) == np.asarray(dr)).all()
-
-
-def test_pairgen_is_lowerable_for_tpu_style_blocks():
-    """The kernel traces + lowers with MXU-aligned blocks (no interpret)."""
-    import jax
-
-    db = random_dbmart(np.random.default_rng(4), n_patients=8, max_events=100)
-    fn = lambda p, d, n: ops.pairgen(p, d, n, interpret=True)
-    jax.jit(fn).lower(db.phenx, np.asarray(db.date), db.nevents)

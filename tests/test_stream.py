@@ -184,6 +184,40 @@ def test_sketch_merges_with_batch_screen_counts():
     assert (merged == full_cnt).all()
 
 
+@pytest.mark.parametrize("seed", range(4))
+def test_sketch_fold_merges_like_a_sort(seed):
+    """The fold's sort-free merge returns exactly sort(stored + novel ids),
+    sentinel-padded, over empty, full and overlapping sets and duplicate
+    slab ids."""
+    import jax.numpy as jnp
+
+    from repro.core.encoding import SENTINEL
+    from repro.stream import counts as counts_lib
+
+    rng = np.random.default_rng(seed)
+    B, C, T = 4, 16, 24
+    stored = np.full((B, C), SENTINEL, np.int64)
+    for b, k in enumerate((0, C, 5, 11)):
+        stored[b, :k] = np.sort(rng.choice(40, size=k, replace=False))
+    seq = rng.integers(0, 40, size=(B, T)).astype(np.int64)
+    mask = rng.random((B, T)) < 0.8
+    table = jnp.zeros(1 << H, jnp.int32)
+    counts, merged, n_novel = counts_lib.sketch_update(
+        table, jnp.asarray(stored), jnp.asarray(seq), jnp.asarray(mask), H)
+
+    want = np.full((B, C + T), SENTINEL, np.int64)
+    want_table = np.zeros(1 << H, np.int32)
+    for b in range(B):
+        old = set(stored[b][stored[b] != SENTINEL].tolist())
+        novel = sorted(set(seq[b][mask[b]].tolist()) - old)
+        want[b, :len(old) + len(novel)] = sorted(old | set(novel))
+        assert int(n_novel[b]) == len(novel)
+        np.add.at(want_table, np.asarray(sparsity.hash_bucket(
+            np.asarray(novel, np.int64), H)), 1)
+    np.testing.assert_array_equal(np.asarray(merged), want)
+    np.testing.assert_array_equal(np.asarray(counts), want_table)
+
+
 def test_sketch_error_is_one_sided():
     """Collisions may false-keep, but a non-sparse sequence NEVER drops."""
     rng = np.random.default_rng(5)
