@@ -122,7 +122,7 @@ def mining_fused(n_patients=2048, avg_events=24, threshold=3, repeats=3,
         "wall_ratio": float(wall_ratio), "max_wall_ratio": MAX_WALL_RATIO,
         "autotune_rows": rows,
         "tile_plan": {"pb": plan.pb, "ti": plan.ti, "tj": plan.tj,
-                      "bt": plan.bt, "block_patients": plan.block_patients,
+                      "block_patients": plan.block_patients,
                       "vmem_bytes": plan.vmem_bytes, "source": plan.source},
         "tile_plan_analytic": {"pb": analytic.pb,
                                "block_patients": analytic.block_patients},
@@ -142,7 +142,7 @@ def main(small=True, json_path=None, backend="jnp"):
           f"fused={r['working_set_fused_bytes']};"
           f"ratio={r['peak_ratio']:.1f}x (P-invariance asserted)")
     p = r["tile_plan"]
-    print(f"mining_fused/tile_plan,,pb={p['pb']};bt={p['bt']};"
+    print(f"mining_fused/tile_plan,,pb={p['pb']};"
           f"block={p['block_patients']};source={p['source']}")
     if json_path:
         with open(json_path, "w") as f:

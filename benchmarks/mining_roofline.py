@@ -71,7 +71,7 @@ def main():
     print(f"mining_roofline/fused_memory_model,,corpus={corpus};"
           f"fused_peak={fused_peak};ratio={corpus/max(fused_peak,1):.1f}x")
     print(f"mining_roofline/fused_tile_plan,,pb={plan.pb};ti={plan.ti};"
-          f"tj={plan.tj};bt={plan.bt};block={plan.block_patients};"
+          f"tj={plan.tj};block={plan.block_patients};"
           f"vmem={plan.vmem_bytes};source={plan.source}")
     return {"pairs_per_s_cpu": n_pairs / dt, "tpu_bound": tpu_bound,
             "corpus_bytes": corpus, "fused_peak_bytes": fused_peak}

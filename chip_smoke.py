@@ -10,7 +10,8 @@ One process runs every phase with ``backend="auto"`` and telemetry on:
     events, ``synthea.generate_benchmark_rows``) through the corpus-free
     fused screen: the fused kernel counts pass 1, the pairgen kernel
     re-mines pass 2.  Exactness: pass-1 table and survivors of a
-    full-width 256-patient slice against ``backend="jnp"``;
+    full-width 256-patient slice against ``backend="jnp"``, at the run's
+    threshold and at one where the screen drops rows;
   * streaming ingest — patients of that cohort (256 by default, of the
     configuration's 1,024: ``--stream-patients``) replayed in 4 waves
     (``launch.stream.replay_waves``) through the delta kernel; the final
@@ -20,8 +21,9 @@ One process runs every phase with ``backend="auto"`` and telemetry on:
     (``screen().starts_with(code).min_duration(days)``); every mask must
     byte-equal frame evaluation on the served view;
   * sharded streaming (``--chips 4`` only) — the streaming cohort over 4
-    device-pinned shards with rebalancing, compared by
-    ``launch.stream.state_digest`` with a one-shard run.
+    device-pinned shards with rebalancing, compared with a one-shard run
+    by a digest of the canonically ordered corpus, the support table and
+    the pid table.
 
 Each phase reads the ``kernel.dispatch`` counters (``repro.obs``) and fails
 when a dispatch that should have run a compiled kernel ran the jnp
@@ -51,8 +53,9 @@ BATCH_BUCKETS_LOG2 = 14       # the fused kernel's table regime
 CHECK_PATIENTS = 256
 STREAM_PATIENTS = 1024        # the streaming cohort of the configuration
 # what a default run streams: on a v5e a 16-patient tick of this cohort
-# takes ~2.3 s and each new slab shape compiles for 5-25 s, so 1,024
-# patients (256 ticks) would bring a cold run near its 1200 s limit
+# takes seconds of device time and each new (set, slab) shape compiles
+# for ~10 s, so 1,024 patients (256 ticks) would bring a cold run near its
+# 1200 s limit
 STREAM_RUN_PATIENTS = 256
 STREAM_WAVES = 4
 TICK_PATIENTS = 16
@@ -167,6 +170,24 @@ def canonical(seq, dur, patient):
     return seq[order], dur[order], patient[order]
 
 
+def canonical_digest(svc) -> str:
+    """Hex digest of a stream service's corpus in canonical row order, its
+    support table and its pid table.  ``launch.stream.state_digest`` keeps
+    the corpus in snapshot order (the replay drill's byte-exact key); a
+    sharded snapshot concatenates shards, so it is compared in this order."""
+    import hashlib
+    snap = svc.snapshot()
+    h = hashlib.sha256()
+    for arr in (*canonical(*(np.asarray(getattr(snap, k))
+                             for k in ("seq", "dur", "patient"))),
+                np.asarray(snap.counts)):
+        h.update(np.ascontiguousarray(arr).tobytes())
+    pids = svc.pids if hasattr(svc, "shards") else svc.store.pids
+    h.update(repr(sorted((str(k), int(v))
+                         for k, v in dict(pids).items())).encode())
+    return h.hexdigest()
+
+
 def _reference_device():
     """The host CPU: references run apart from the chip under test."""
     import jax
@@ -206,8 +227,37 @@ def batch_fit_phase(db, *, threshold: int = THRESHOLD,
     if limit and out["peak_bytes"] is not None:
         out["peak_share"] = out["peak_bytes"] / limit
 
+    # At H = 14 every bucket of a Table 1 cohort (and of the slice) holds
+    # far more than ``threshold`` patients, so the fit above screens
+    # nothing.  The slice is also fitted at one above its median bucket
+    # support, where the screen drops rows and pass 2 compacts survivors.
     sub = db.slice_patients(0, min(check_patients, db.n_patients),
                             db.phenx.shape[1])
+    out["check_patients"] = sub.n_patients
+    out["check_pairs"] = int(np.sum(sub.nevents.astype(np.int64)
+                                    * (sub.nevents - 1) // 2))
+    t0 = time.perf_counter()
+    exact, table, _ = _slice_exact(cfg, sub)
+    nz = table[table > 0]
+    drop_threshold = int(np.median(nz)) + 1 if len(nz) else 1
+    exact_drop, _, out["check_kept_rows"] = _slice_exact(
+        cfg.replace(threshold=drop_threshold), sub)
+    out["check_s"] = time.perf_counter() - t0
+    out["check_screen_threshold"] = drop_threshold
+    if out["check_kept_rows"] >= out["check_pairs"]:
+        raise SystemExit(f"[batch_fit] threshold {drop_threshold} dropped "
+                         "no row of the check slice")
+    out["exact"] = exact and exact_drop
+    return out
+
+
+def _slice_exact(cfg, sub):
+    """Fit ``sub`` under ``cfg`` and under the jnp reference on the host;
+    returns (pass-1 tables and survivors byte-equal, the pass-1 table,
+    surviving rows)."""
+    import jax
+    from repro.api import MiningSession
+
     got = MiningSession(cfg).fit(sub)
     with jax.default_device(_reference_device()):
         want = MiningSession(cfg.replace(backend="jnp",
@@ -215,12 +265,12 @@ def batch_fit_phase(db, *, threshold: int = THRESHOLD,
         want_arrays = want.arrays()
     g_seq, g_dur, g_pat, _ = got.arrays()
     w_seq, w_dur, w_pat, _ = want_arrays
-    out["check_patients"] = sub.n_patients
-    out["exact"] = bool(
-        np.array_equal(got._corpus.counts(), want._corpus.counts())
+    table = got._corpus.counts()
+    exact = bool(
+        np.array_equal(table, want._corpus.counts())
         and np.array_equal(g_seq, w_seq) and np.array_equal(g_dur, w_dur)
         and np.array_equal(g_pat, w_pat))
-    return out
+    return exact, table, len(g_seq)
 
 
 def stream_phase(db, *, n_patients: int = STREAM_PATIENTS,
@@ -310,10 +360,10 @@ def sharded_phase(db, *, n_shards: int = 4, waves: int = STREAM_WAVES,
                   threshold: int = THRESHOLD, rebalance_every: int = 8,
                   seed: int = 0) -> dict:
     """``db`` over ``n_shards`` device-pinned shards with rebalancing,
-    compared by state digest with a one-shard run of the same replay."""
+    compared by canonical digest with a one-shard run of the same replay."""
     from repro.api import MiningConfig, MiningSession
     from repro.launch.mesh import make_data_mesh
-    from repro.launch.stream import replay_waves, state_digest
+    from repro.launch.stream import replay_waves
 
     base = MiningConfig(threshold=threshold, screen="hash", backend="auto",
                         tick_patients=tick_patients, telemetry=True)
@@ -327,7 +377,7 @@ def sharded_phase(db, *, n_shards: int = 4, waves: int = STREAM_WAVES,
     with timed(out):
         for _ in replay_waves(db, session, waves, seed):
             session.run()
-        digest = state_digest(session.service)
+        digest = canonical_digest(session.service)
     out["impl"] = check_impl("sharded", session, ("delta",))
     snap = session.telemetry.metrics.snapshot()
     out["migrations"] = len(session.service.migrations)
@@ -342,7 +392,7 @@ def sharded_phase(db, *, n_shards: int = 4, waves: int = STREAM_WAVES,
         single.run()
     out["one_shard_wall_s"] = time.perf_counter() - t0
     out["digest"] = digest[:16]
-    out["exact"] = digest == state_digest(single.service)
+    out["exact"] = digest == canonical_digest(single.service)
     return out
 
 

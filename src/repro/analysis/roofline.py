@@ -13,8 +13,8 @@ params for MoE) over HLO FLOPs measures useful-compute fraction.
 This module also owns the *mining* cost model (constants shared with
 ``benchmarks/mining_roofline.py``) and :func:`mining_tile_plan`, the tile
 selection the fused mine+screen kernel (``kernels/tspm_fused``) reads its
-defaults from — analytic VMEM-fit by default, measured-sweep argmin when
-the autotune rows from ``benchmarks/mining_fused.py`` are handed back in.
+defaults from — an 8-patient block by default, the fastest VMEM-fitting
+autotune row when ``benchmarks/mining_fused.py`` hands its rows back in.
 """
 from __future__ import annotations
 
@@ -42,27 +42,19 @@ class MiningTilePlan:
     """Tile choice for the fused mine+screen kernel (kernels/tspm_fused).
 
     ``pb x ti x tj`` is the pair-tile grid shared with tspm_pairgen /
-    tspm_delta; ``bt`` the width of the VMEM-resident bucket table the
-    kernel accumulates (the whole [2^H] table, capped at the kernel
-    regime's 2^14); ``block_patients`` the host-loop patient block
-    bounding the corpus-free counting pass's working set."""
+    tspm_delta (the kernel keeps the whole [2^H] bucket table in VMEM);
+    ``block_patients`` the host-loop patient block bounding the
+    corpus-free counting pass's working set."""
 
     pb: int
     ti: int
     tj: int
-    bt: int
     block_patients: int
     vmem_bytes: int          # modeled per-grid-step VMEM working set
     source: str              # 'analytic' | 'measured'
 
 
-# largest bucket table the fused kernel keeps in VMEM: its one-hot matmul
-# histogram costs O(pairs * 2^H) MXU work, and past 2^14 buckets the jnp
-# block fallback wins (kernels/tspm_fused/ops.KERNEL_MAX_LOG2)
-FUSED_MAX_LOG2 = 14
-
-
-def fused_kernel_vmem(pb: int, ti: int, tj: int, bt: int,
+def fused_kernel_vmem(pb: int, ti: int, tj: int, n_buckets_log2: int,
                       max_events: int) -> int:
     """Modeled VMEM bytes of one fused-kernel grid step.
 
@@ -77,7 +69,7 @@ def fused_kernel_vmem(pb: int, ti: int, tj: int, bt: int,
     tiles = pb * (ti + tj) * 4            # xi / xj row tiles
     dedup = pb * (ti + tj) * e * 4        # eq_i / eq_j masks
     pairs = pb * ti * tj * (4 + 4 + 1 + 4)  # hash, iota masks, flags, scratch
-    table_rows = max(8, bt // 128)
+    table_rows = max(8, (1 << n_buckets_log2) // 128)
     hist = (table_rows + 128) * 8 * tj * 2  # one-hot planes (bf16)
     table = table_rows * 128 * (4 + 4)    # int32 table + f32 accumulator
     return int(rows + tiles + dedup + pairs + hist + table)
@@ -87,28 +79,26 @@ def mining_tile_plan(max_events: int, n_buckets_log2: int, *,
                      vmem_bytes: int = VMEM_BYTES // 2,
                      block_bytes: int = 64 << 20,
                      rows: list[dict] | None = None) -> MiningTilePlan:
-    """Pick (pb, ti, tj, bt, block_patients) for the fused kernel.
+    """Pick (pb, ti, tj, block_patients) for the fused kernel.
 
     Analytic mode: lane-native ``ti = tj = 128`` (matching the ops-layer
-    padding), ``pb = 8`` (one sublane tile of patients), ``bt = min(2^H,
-    2^14)``, and a counting-pass patient block sized so the jnp-fallback
-    dense planes stay under ``block_bytes`` at
-    ``FUSED_BLOCK_BYTES_PER_PAIR``.
+    padding), ``pb = 8`` (one sublane tile of patients), and a
+    counting-pass patient block sized so the jnp-fallback dense planes
+    stay under ``block_bytes`` at ``FUSED_BLOCK_BYTES_PER_PAIR``.
 
     Measured mode: ``rows`` are autotune sweep records (dicts with ``pb``
     and ``wall_s``, optionally ``ti``/``tj``, from
     ``benchmarks/mining_fused.py``); the fastest row that still fits
     ``vmem_bytes`` wins, falling back to the analytic choice when none fit.
     """
-    B = 1 << n_buckets_log2
     ti = tj = 128
-    bt = min(B, 1 << FUSED_MAX_LOG2)
     chosen = None
     source = "analytic"
     if rows:
         fitting = [r for r in rows
                    if fused_kernel_vmem(int(r["pb"]), int(r.get("ti", ti)),
-                                        int(r.get("tj", tj)), bt, max_events)
+                                        int(r.get("tj", tj)), n_buckets_log2,
+                                        max_events)
                    <= vmem_bytes]
         if fitting:
             best = min(fitting, key=lambda r: float(r["wall_s"]))
@@ -124,8 +114,9 @@ def mining_tile_plan(max_events: int, n_buckets_log2: int, *,
     e = max(ti, -(-max(int(max_events), 1) // ti) * ti)
     blk = max(pb, int(block_bytes // max(e * e * FUSED_BLOCK_BYTES_PER_PAIR, 1)))
     blk = min(-(-blk // pb) * pb, 4096)
-    return MiningTilePlan(pb=pb, ti=ti, tj=tj, bt=bt, block_patients=blk,
-                          vmem_bytes=fused_kernel_vmem(pb, ti, tj, bt,
+    return MiningTilePlan(pb=pb, ti=ti, tj=tj, block_patients=blk,
+                          vmem_bytes=fused_kernel_vmem(pb, ti, tj,
+                                                       n_buckets_log2,
                                                        max_events),
                           source=source)
 

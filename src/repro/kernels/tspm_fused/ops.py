@@ -2,7 +2,7 @@
 
 Mirrors tspm_pairgen/ops.py (padding recipe, interpret default).  Tile
 sizes come from
-``analysis.roofline.mining_tile_plan`` — analytic VMEM fit by default,
+``analysis.roofline.mining_tile_plan`` — an 8-patient block by default,
 measured autotune rows when ``benchmarks/mining_fused.py`` hands them in.
 """
 from __future__ import annotations
@@ -17,7 +17,10 @@ from repro.kernels.tspm_fused import fused as _k
 from repro.kernels.tspm_fused import ref as _ref
 from repro.kernels.util import pad_to as _pad_to
 
-KERNEL_MAX_LOG2 = roofline.FUSED_MAX_LOG2
+# largest bucket table the kernel keeps in VMEM: its one-hot matmul
+# histogram costs O(pairs * 2^H) MXU work, and past 2^14 buckets the jnp
+# block reference wins
+KERNEL_MAX_LOG2 = 14
 
 
 def _kernel_block(phenx, nevents, codec, n_buckets_log2, plan, pb, tile,

@@ -57,16 +57,12 @@ def replay_waves(db, svc, n_waves: int, seed: int = 0, start_wave: int = 0):
 def state_digest(svc) -> str:
     """One hex digest over everything the journal replay must reproduce
     (corpus, sketch table, pid table) — the cross-process comparison key
-    for the replay drill.  Corpus rows are hashed in canonical (seq,
-    patient, dur) order, so a sharded service and a one-shard service that
-    mined the same cohort give the same digest."""
+    for the replay drill."""
     snap = svc.snapshot()
-    seq, dur, pat = (np.asarray(getattr(snap, k))
-                     for k in ("seq", "dur", "patient"))
-    order = np.lexsort((dur, pat, seq))
     h = hashlib.sha256()
-    for arr in (seq[order], dur[order], pat[order], np.asarray(snap.counts)):
-        h.update(np.ascontiguousarray(arr).tobytes())
+    for name in ("seq", "dur", "patient", "counts"):
+        h.update(np.ascontiguousarray(
+            np.asarray(getattr(snap, name))).tobytes())
     pids = svc.pids if hasattr(svc, "shards") else svc.store.pids
     h.update(repr(sorted((str(k), int(v))
                          for k, v in dict(pids).items())).encode())

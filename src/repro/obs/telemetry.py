@@ -31,8 +31,8 @@ def default_hot_functions() -> tuple:
     from repro.stream import delta as delta_lib
     from repro.stream import store as store_lib
 
-    return (store_lib._append_step, counts_lib.sorted_slab,
-            counts_lib.sketch_fold, delta_lib.delta_mine_jnp)
+    return (store_lib._append_step, counts_lib.sketch_update,
+            delta_lib.delta_mine_jnp)
 
 
 def jit_cache_size(fns) -> int:

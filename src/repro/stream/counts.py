@@ -34,60 +34,28 @@ from repro.core import sparsity
 from repro.core.encoding import SENTINEL
 
 
+@functools.partial(jax.jit, static_argnames=("n_buckets_log2",))
 def sketch_update(counts, stored, seq, mask, n_buckets_log2: int):
     """One tick: (counts', merged per-patient sets, per-row novel counts).
 
     ``stored`` [B, C] are the patients' sorted sentinel-padded sequence
-    sets; ``seq``/``mask`` [B, T] the tick's delta slab rows.  Two jitted
-    steps: the slab sort compiles per slab width only, the fold per (set
-    width, slab width) — an int64 sort costs ~10 s to compile for a TPU
-    v5e whatever its length, so the fold holds none.
+    sets; ``seq``/``mask`` [B, T] the tick's delta slab rows.
     """
-    srt, first = sorted_slab(seq, mask)
-    return sketch_fold(counts, stored, srt, first, n_buckets_log2)
-
-
-@jax.jit
-def sorted_slab(seq, mask):
-    """Row-sorted sentinel-padded slab ids and their first-occurrence
-    flags (the batch screen's dedup)."""
-    flat = jnp.where(mask, jnp.asarray(seq, jnp.int64),
-                     SENTINEL).reshape(seq.shape[0], -1)
-    # plain keys with no payload: an unstable sort returns the same array
-    # and compiles ~2.5x faster on TPU (v5e, int64 rows)
-    srt = jnp.sort(flat, axis=1, stable=False)
-    return srt, sparsity.row_first_flags(srt)
-
-
-@functools.partial(jax.jit, static_argnames=("n_buckets_log2",))
-def sketch_fold(counts, stored, srt, first, n_buckets_log2: int):
-    """Fold a sorted slab into the sets: ids absent from ``stored`` are
-    novel, bump their buckets, and merge into the sets in sorted order."""
     B, C = stored.shape
-    T = srt.shape[1]
-    idx = jax.vmap(jnp.searchsorted)(stored, srt)   # stored ids below each
+    flat = jnp.where(mask, jnp.asarray(seq, jnp.int64), SENTINEL).reshape(B, -1)
+    # plain keys with no payload: an unstable sort returns the same array,
+    # and both int64 sorts compile ~2.4x faster for a TPU v5e
+    srt = jnp.sort(flat, axis=1, stable=False)
+    first = sparsity.row_first_flags(srt)   # same dedup as the batch screen
+    idx = jax.vmap(jnp.searchsorted)(stored, srt)
     present = jnp.take_along_axis(stored, jnp.clip(idx, 0, C - 1), axis=1) == srt
     novel = first & ~present
     h = sparsity.hash_bucket(srt, n_buckets_log2)
     counts = counts.at[h.reshape(-1)].add(novel.reshape(-1).astype(jnp.int32))
-    # sorted(stored + novel) by position arithmetic instead of a second
-    # sort: a novel id lands after the stored ids and the novel ids below
-    # it, a stored id after the novel ids below it.  Sentinel padding
-    # fills the rest, as the sorted concatenation would.
-    rank = jnp.cumsum(novel, axis=1, dtype=jnp.int32)
-    below = jax.vmap(jnp.searchsorted)(srt, stored)   # slab ids below each
-    novel_below = jnp.take_along_axis(
-        jnp.pad(rank, ((0, 0), (1, 0))), below, axis=1)
-    cols = jnp.arange(C + T, dtype=jnp.int32)
-    pos_old = cols[None, :C] + novel_below
-    # ids that are not novel get distinct out-of-range slots and drop
-    pos_new = jnp.where(novel, rank - 1 + idx, C + T + cols[None, :T])
-    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
-    merged = jnp.full((B, C + T), SENTINEL, jnp.int64)
-    merged = merged.at[rows, pos_old].set(stored, unique_indices=True)
-    merged = merged.at[rows, pos_new].set(srt, mode="drop",
-                                          unique_indices=True)
-    return counts, merged, jnp.sum(novel, axis=1, dtype=jnp.int32)
+    merged = jnp.sort(
+        jnp.concatenate([stored, jnp.where(novel, srt, SENTINEL)], axis=1),
+        axis=1, stable=False)
+    return counts, merged, jnp.sum(novel, axis=1).astype(jnp.int32)
 
 
 class _PendingSketchUpdate:

@@ -35,6 +35,8 @@ def test_batch_fit_phase_is_exact(cohort):
                                      budget_bytes=1 << 22)
     assert out["exact"]
     assert out["kept_rows"] > 0
+    # the second slice threshold really screens
+    assert 0 < out["check_kept_rows"] < out["check_pairs"]
     assert out["impl"] == {"fused": "jnp(interpret=False,calls=1)",
                            "pairgen": "jnp(interpret=False,calls=1)"}
     assert out["compile_s"] >= 0 and out["run_s"] > 0
@@ -64,6 +66,35 @@ def test_sharded_phase_matches_one_shard(cohort):
     assert out["exact"]
     assert out["migrations"] > 0
     assert out["merge_path"]
+
+
+def test_state_digest_stays_byte_exact_while_the_check_canonicalises():
+    """The journal replay drill's state_digest hashes the corpus in
+    snapshot order, so a replay that reorders rows fails it; the sharded
+    check's canonical digest accepts the reordering."""
+    import types
+
+    import numpy as np
+
+    from repro.launch.stream import state_digest
+    from repro.stream.service import Snapshot
+
+    rng = np.random.default_rng(0)
+    seq = rng.integers(0, 1 << 40, 32)
+    dur = rng.integers(0, 90, 32).astype(np.int32)
+    pat = rng.integers(0, 4, 32).astype(np.int32)
+    counts = rng.integers(0, 9, 1 << 6).astype(np.int32)
+    perm = rng.permutation(32)
+
+    def svc(order):
+        snap = Snapshot(seq[order], dur[order], pat[order], counts, 6)
+        return types.SimpleNamespace(
+            snapshot=lambda: snap,
+            store=types.SimpleNamespace(pids={"a": 0, "b": 1}))
+
+    a, b = svc(np.arange(32)), svc(perm)
+    assert state_digest(a) != state_digest(b)
+    assert chip_smoke.canonical_digest(a) == chip_smoke.canonical_digest(b)
 
 
 def test_check_impl_rejects_the_wrong_implementation(cohort):

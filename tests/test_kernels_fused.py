@@ -249,12 +249,14 @@ def test_tile_plan_analytic_defaults():
     plan = roofline.mining_tile_plan(96, 12)
     assert plan.source == "analytic"
     assert plan.ti == plan.tj == 128
-    assert (1 << 12) % plan.bt == 0
     assert plan.block_patients % plan.pb == 0
     assert plan.vmem_bytes <= roofline.VMEM_BYTES // 2
-    # bigger tables never pick a bucket tile wider than the table
+    # the modeled working set holds the whole [2^H] table
     small = roofline.mining_tile_plan(96, 8)
-    assert small.bt == 256
+    assert small.pb == plan.pb
+    assert plan.vmem_bytes == roofline.fused_kernel_vmem(plan.pb, 128, 128,
+                                                         12, 96)
+    assert small.vmem_bytes < plan.vmem_bytes
 
 
 def test_tile_plan_pins_measured_rows():
@@ -266,7 +268,7 @@ def test_tile_plan_pins_measured_rows():
     plan = roofline.mining_tile_plan(96, 12, rows=rows)
     assert plan.source == "measured"
     assert plan.pb == 8
-    assert roofline.fused_kernel_vmem(512, 128, 128, 512, 96) \
+    assert roofline.fused_kernel_vmem(512, 128, 128, 9, 96) \
         > roofline.VMEM_BYTES // 2
     # no fitting row at all -> analytic fallback
     plan2 = roofline.mining_tile_plan(96, 12, rows=[rows[2]])

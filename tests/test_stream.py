@@ -186,9 +186,9 @@ def test_sketch_merges_with_batch_screen_counts():
 
 @pytest.mark.parametrize("seed", range(4))
 def test_sketch_fold_merges_like_a_sort(seed):
-    """The fold's sort-free merge returns exactly sort(stored + novel ids),
+    """The fold's merge returns exactly sort(stored + novel ids),
     sentinel-padded, over empty, full and overlapping sets and duplicate
-    slab ids."""
+    slab ids (its sorts are unstable: plain keys, no payload)."""
     import jax.numpy as jnp
 
     from repro.core.encoding import SENTINEL
