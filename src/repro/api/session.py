@@ -112,7 +112,7 @@ class MiningSession:
             budget_bytes=c.budget_bytes or (1 << 28), codec=c.codec,
             backend=c.backend, n_buckets_log2=c.n_buckets_log2,
             fuse_duration=c.fuse_duration, bucket_days=c.bucket_days,
-            metrics=self.telemetry.metrics)
+            telemetry=self.telemetry)
         return self._frame(out["seq"], out["dur"], out["patient"],
                            counts=out["counts"], vocab=db.vocab,
                            n_patients=db.n_patients)
@@ -156,7 +156,7 @@ class MiningSession:
                 budget_bytes=c.budget_bytes or (1 << 28), codec=c.codec,
                 backend=c.backend, n_buckets_log2=c.n_buckets_log2,
                 fuse_duration=c.fuse_duration, bucket_days=c.bucket_days,
-                metrics=self.telemetry.metrics)
+                telemetry=self.telemetry)
             if c.spill_dir:
                 os.makedirs(c.spill_dir, exist_ok=True)
                 np.save(os.path.join(c.spill_dir, "bucket_counts.npy"),

@@ -114,7 +114,7 @@ def mine_chunked(db: DBMart, budget_bytes: int = 1 << 28, threshold: int | None 
 def mine_fused(db: DBMart, threshold: int, budget_bytes: int = 1 << 28,
                codec: str = "bit", backend: str = "jnp",
                n_buckets_log2: int = 20, fuse_duration: bool = False,
-               bucket_days: int = 30, metrics=obs_lib.NOOP_REGISTRY) -> dict:
+               bucket_days: int = 30, telemetry=obs_lib.NOOP) -> dict:
     """Screen-then-materialize: corpus-free counting, survivors-only pairs.
 
     Pass 1 builds the global [2^H] bucket table with the fused mine+screen
@@ -125,34 +125,88 @@ def mine_fused(db: DBMart, threshold: int, budget_bytes: int = 1 << 28,
     themselves.  Byte-identical to mine + hash screen (keeping is per-id,
     so supports and canonical order are preserved).
 
+    ``telemetry`` gets the spans ``fit.pass1``, one ``fit.pass2`` per
+    chunk with the phases ``.dispatch`` (enqueue of pairgen and the
+    screen), ``.wait``, ``.fetch`` and ``.compact`` inside (the last three
+    announced by ``sparsity.screen_survivors``), and ``fit.assemble``; and
+    the counters ``fit.pairs`` (real pairs), ``fit.pass1.slots`` (pair
+    slots pass 1 computes, padding included) and ``fit.pass2.fetch_bytes``
+    — host integers from shapes, never a device read.
+
     Returns compacted numpy {seq, dur, patient} (every row real) plus the
     global 'counts' table.
     """
     from repro.kernels.tspm_fused import ops as fused_ops
 
-    counts = np.asarray(fused_ops.fused_bucket_counts(
-        db.phenx, db.date, db.nevents, codec=codec,
-        fuse_duration=fuse_duration, bucket_days=bucket_days,
-        n_buckets_log2=n_buckets_log2, backend=backend, metrics=metrics))
-    chunks = plan_chunks(np.asarray(db.nevents), budget_bytes)
+    tracer, m = telemetry.tracer, telemetry.metrics
+    fetch_bytes = m.counter("fit.pass2.fetch_bytes")
+    nevents = np.asarray(db.nevents)
+    if telemetry.enabled:
+        n = nevents.astype(np.int64)
+        m.counter("fit.pairs").inc(int(np.sum(n * (n - 1) // 2)))
+    cp = fused_ops.counting_plan(*db.phenx.shape, n_buckets_log2, backend,
+                                 fuse_duration)
+    m.counter("fit.pass1.slots").inc(cp.slots)
+    with tracer.span("fit.pass1", impl="kernel" if cp.use_kernel else "jnp",
+                     blocks=cp.n_blocks, H=n_buckets_log2):
+        counts = np.asarray(fused_ops.fused_bucket_counts(
+            db.phenx, db.date, db.nevents, codec=codec,
+            fuse_duration=fuse_duration, bucket_days=bucket_days,
+            n_buckets_log2=n_buckets_log2, backend=backend, metrics=m))
+    chunks = plan_chunks(nevents, budget_bytes)
     parts = []
     for ch in chunks:
-        sub = db.slice_patients(ch.start, ch.stop, ch.max_events)
-        mined = mining.mine(sub.phenx, sub.date, sub.nevents, codec=codec,
-                            fuse_duration=fuse_duration,
-                            bucket_days=bucket_days, backend=backend,
-                            metrics=metrics)
-        P = mined.seq.shape[0]
-        pat = np.broadcast_to(
-            np.arange(ch.start, ch.start + P, dtype=np.int32).reshape(
-                (P,) + (1,) * (mined.seq.ndim - 1)), mined.seq.shape)
-        parts.append(sparsity.screen_survivors(
-            mined.seq, mined.dur, pat, counts, threshold, n_buckets_log2,
-            mask=mined.mask))
-    cat = lambda k, dt: (np.concatenate([p[k] for p in parts]) if parts
-                         else np.zeros(0, dt))
-    return {"seq": cat(0, np.int64), "dur": cat(1, np.int32),
-            "patient": cat(2, np.int32), "counts": counts}
+        with tracer.span("fit.pass2", patients=ch.n_patients,
+                         E=ch.max_events), \
+                _Phases(tracer, fetch_bytes) as phase:
+            phase("dispatch")
+            sub = db.slice_patients(ch.start, ch.stop, ch.max_events)
+            mined = mining.mine(sub.phenx, sub.date, sub.nevents, codec=codec,
+                                fuse_duration=fuse_duration,
+                                bucket_days=bucket_days, backend=backend,
+                                metrics=m)
+            P = mined.seq.shape[0]
+            pat = np.broadcast_to(
+                np.arange(ch.start, ch.start + P, dtype=np.int32).reshape(
+                    (P,) + (1,) * (mined.seq.ndim - 1)), mined.seq.shape)
+            part = sparsity.screen_survivors(
+                mined.seq, mined.dur, pat, counts, threshold, n_buckets_log2,
+                mask=mined.mask, phase=phase)
+            phase.close(survivors=len(part[0]))
+        parts.append(part)
+    with tracer.span("fit.assemble"):
+        cat = lambda k, dt: (np.concatenate([p[k] for p in parts]) if parts
+                             else np.zeros(0, dt))
+        out = {"seq": cat(0, np.int64), "dur": cat(1, np.int32),
+               "patient": cat(2, np.int32), "counts": counts}
+    return out
+
+
+class _Phases:
+    """The consecutive phase spans of one pass-2 chunk: ``phase(name,
+    **args)`` ends the open phase and begins ``fit.pass2.<name>``, counting
+    a ``bytes`` arg on ``fetch_bytes``; ``close(**args)`` ends the last,
+    as leaving the ``with`` block does on an exception."""
+
+    def __init__(self, tracer, fetch_bytes):
+        self.tracer, self.fetch_bytes, self.open = tracer, fetch_bytes, None
+
+    def __call__(self, name, **args):
+        self.close()
+        if "bytes" in args:
+            self.fetch_bytes.inc(args["bytes"])
+        self.open = self.tracer.begin("fit.pass2." + name, **args)
+
+    def close(self, **args):
+        if self.open is not None:
+            self.tracer.finish(self.open, **args)
+            self.open = None
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.close()
 
 
 def mine_to_files(db: DBMart, out_dir: str, budget_bytes: int = 1 << 28,

@@ -177,8 +177,12 @@ def screen_hash_from_counts(seq, mask, counts, threshold, n_buckets_log2: int):
     return keep & jnp.asarray(mask, bool)
 
 
+def _no_phase(name, **args):
+    pass
+
+
 def screen_survivors(seq, dur, patient, counts, threshold,
-                     n_buckets_log2: int, mask=None):
+                     n_buckets_log2: int, mask=None, phase=_no_phase):
     """Host-compacted survivors of the hash screen (corpus-free path).
 
     The materialization half of ``screen="fused"``: given the global
@@ -188,14 +192,28 @@ def screen_survivors(seq, dur, patient, counts, threshold,
     so supports, re-screens and the canonical lexsort order of the
     compacted arrays are byte-identical to screening the materialized
     corpus with the same table.
+
+    Once the screen is enqueued, ``phase(name, **args)`` is called as each
+    host phase starts, so a caller can time them (``chunking.mine_fused``
+    makes them spans): ``"wait"`` until what is fetched is ready,
+    ``"fetch"`` (``bytes=``) the device-to-host copies of the keep, id and
+    duration planes, all three before any is indexed, and ``"compact"``
+    the boolean indexing.
     """
     # the screen runs in the caller's layout and only the host flattens:
     # an eager TPU reshape of a [P, E, E] bool plane can take minutes to
     # compile (v5e, P=256, E=536)
     seq = jnp.asarray(seq, jnp.int64)
     mask = seq != SENTINEL if mask is None else jnp.asarray(mask, bool)
-    keep = np.asarray(screen_hash_from_counts(
-        seq, mask, jnp.asarray(counts), threshold, n_buckets_log2)).reshape(-1)
-    return (np.asarray(seq).reshape(-1)[keep],
-            np.asarray(dur, np.int32).reshape(-1)[keep],
+    keep = screen_hash_from_counts(seq, mask, jnp.asarray(counts), threshold,
+                                   n_buckets_log2)
+    phase("wait")
+    jax.block_until_ready((keep, seq, dur))
+    phase("fetch", bytes=sum(a.nbytes for a in (keep, seq, dur)
+                             if isinstance(a, jax.Array)))
+    keep, seq = np.asarray(keep), np.asarray(seq)
+    dur = np.asarray(dur, np.int32)
+    phase("compact")
+    keep = keep.reshape(-1)
+    return (seq.reshape(-1)[keep], dur.reshape(-1)[keep],
             np.asarray(patient, np.int32).reshape(-1)[keep])

@@ -7,6 +7,8 @@ measured autotune rows when ``benchmarks/mining_fused.py`` hands them in.
 """
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,18 +25,59 @@ from repro.kernels.util import pad_to as _pad_to
 KERNEL_MAX_LOG2 = 14
 
 
+def _kernel_dims(P, E, plan, pb, tile) -> tuple[int, int]:
+    """(patient rows, lane tile) the kernel pads a [P, E] block to."""
+    tile = int(tile or plan.ti)
+    pb = min(int(pb or plan.pb), P)
+    return pb, min(tile, max(128, 1 << int(np.ceil(np.log2(max(E, 1))))))
+
+
 def _kernel_block(phenx, nevents, codec, n_buckets_log2, plan, pb, tile,
                   interpret):
     P, E = phenx.shape
-    tile = int(tile or plan.ti)
-    pb = min(int(pb or plan.pb), P)
-    t = min(tile, max(128, 1 << int(np.ceil(np.log2(max(E, 1))))))
+    pb, t = _kernel_dims(P, E, plan, pb, tile)
     x = _pad_to(phenx, t, 1)
     x = _pad_to(x, pb, 0)
     nev = _pad_to(nevents, pb, 0)      # padded patients: nevents == 0
     return _k.fused_table(
         x, nev, n_buckets_log2=n_buckets_log2, codec=codec, pb=pb, ti=t,
         tj=t, interpret=interpret)
+
+
+class CountingPlan(NamedTuple):
+    """How :func:`fused_bucket_counts` covers a [P, E] cohort."""
+
+    use_kernel: bool
+    tiles: roofline.MiningTilePlan
+    block_patients: int
+    n_blocks: int
+    slots: int          # pair slots the blocks compute, padding included
+
+
+def counting_plan(P: int, E: int, n_buckets_log2: int = 20,
+                  backend: str = "auto", fuse_duration: bool = False,
+                  block_patients: int | None = None, pb: int | None = None,
+                  tile: int | None = None) -> CountingPlan:
+    """The implementation, patient blocks and computed pair slots of one
+    counting pass (host arithmetic on shapes only).  A block of p patients
+    computes p x E x E slots on the jnp fallback and its kernel-padded
+    planes on the kernel."""
+    if backend == "auto":
+        backend = "kernel" if jax.default_backend() == "tpu" else "jnp"
+    plan = roofline.mining_tile_plan(E, n_buckets_log2)
+    blk = int(block_patients or plan.block_patients)
+    use_kernel = (backend == "kernel" and not fuse_duration
+                  and n_buckets_log2 <= KERNEL_MAX_LOG2)
+    slots = 0
+    for s in range(0, P, blk):
+        p = min(blk, P - s)
+        if use_kernel:
+            rows, t = _kernel_dims(p, E, plan, pb, tile)
+            p, e = -(-p // rows) * rows, -(-E // t) * t
+            slots += p * e * e
+        else:
+            slots += p * E * E
+    return CountingPlan(use_kernel, plan, blk, -(-P // blk), slots)
 
 
 def fused_bucket_counts(phenx, date, nevents, codec: str = "bit",
@@ -56,8 +99,6 @@ def fused_bucket_counts(phenx, date, nevents, codec: str = "bit",
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if backend == "auto":
-        backend = "kernel" if jax.default_backend() == "tpu" else "jnp"
     phenx = jnp.asarray(phenx, jnp.int32)
     date = jnp.asarray(date, jnp.int32)
     nevents = jnp.asarray(nevents, jnp.int32).reshape(-1)
@@ -67,10 +108,9 @@ def fused_bucket_counts(phenx, date, nevents, codec: str = "bit",
         # zero-width-slab guard (mirrors tspm_delta/ops.py): no events,
         # empty table
         return jnp.zeros(1 << H, jnp.int32)
-    plan = roofline.mining_tile_plan(E, H)
-    blk = int(block_patients or plan.block_patients)
-    use_kernel = (backend == "kernel" and not fuse_duration
-                  and H <= KERNEL_MAX_LOG2)
+    cp = counting_plan(P, E, H, backend, fuse_duration, block_patients, pb,
+                       tile)
+    use_kernel, plan, blk = cp.use_kernel, cp.tiles, cp.block_patients
     obs_lib.count_dispatch(metrics, "fused", use_kernel, interpret)
     counts = jnp.zeros(1 << H, jnp.int32)
     for s in range(0, P, blk):

@@ -171,9 +171,7 @@ class QueryServer:
         self._m_misses = m.counter("serve.cache.misses")
         self._m_evictions = m.counter("serve.cache.evictions")
         self._m_hit_ratio = m.gauge("serve.cache.hit_ratio")
-        self._m_staleness = m.gauge("serve.replica_staleness_ticks")
         self._m_wait = m.histogram("serve.wait_s")
-        self._m_eval = m.histogram("serve.eval_s")
 
         self.cache = ResultCache(cache_entries)
         self._prev_hits = self._prev_misses = self._prev_evictions = 0
@@ -222,7 +220,6 @@ class QueryServer:
         entries.  Called automatically at tick boundaries."""
         view = self.replica.publish()
         self.cache.invalidate_below(view.version)
-        self._m_staleness.set(0)
         return view
 
     def view(self):
@@ -243,7 +240,6 @@ class QueryServer:
 
     # --- wave evaluation ----------------------------------------------------
     def _eval_wave(self, view, plans) -> list[QueryResult]:
-        t0 = time.perf_counter()
         sp = self._tracer.begin("serve.eval", cat="host", track="serve",
                                 n=len(plans))
         with self._eval_lock:
@@ -269,10 +265,8 @@ class QueryServer:
                     self.cache.put((key, view.version), keep)
             out = [QueryResult(view, masks[k]) for k in keys]
         self._tracer.finish(sp)
-        self._m_eval.observe(time.perf_counter() - t0)
         self._n_queries += len(plans)
         self._m_queries.inc(len(plans))
-        self._m_staleness.set(self.replica.staleness_ticks())
         self._sync_cache_metrics()
         return out
 

@@ -18,13 +18,11 @@ int32 ``(phenx, date)`` arrays) keyed by patient key:
 :class:`HostTier` is the pre-refactor ``_spilled`` dict behind the
 interface; :class:`DiskTier` persists blocks through
 :class:`~repro.storage.blockstore.CompressedBlockStore` and reports both
-encoded (actual disk) and raw (host-equivalent) bytes, plus
-encode/decode latency histograms and a compression-ratio gauge on the
-``storage.*`` metric namespace.
+encoded (actual disk) and raw (host-equivalent) bytes, plus a
+compression-ratio gauge on the ``storage.*`` metric namespace.
 """
 from __future__ import annotations
 
-import time
 from typing import Protocol, runtime_checkable
 
 import numpy as np
@@ -138,23 +136,17 @@ class DiskTier:
         self._m_raw = obs.metrics.gauge("storage.tier_raw_bytes", **lbl)
         self._m_ratio = obs.metrics.gauge("storage.compression_ratio", **lbl)
         self._m_restores = obs.metrics.counter("storage.restores", **lbl)
-        self._m_enc = obs.metrics.histogram("storage.encode_s", **(labels or {}))
-        self._m_dec = obs.metrics.histogram("storage.decode_s", **(labels or {}))
 
     @property
     def root(self) -> str:
         return self.store.root
 
     def hold(self, key, phenx, date) -> None:
-        t0 = time.perf_counter()
         self.store.put(key, phenx, date)
-        self._m_enc.observe(time.perf_counter() - t0)
         self._sample()
 
     def restore(self, key) -> tuple[np.ndarray, np.ndarray]:
-        t0 = time.perf_counter()
         out = self.store.pop(key)
-        self._m_dec.observe(time.perf_counter() - t0)
         self._m_restores.inc()
         self._sample()
         return out
