@@ -127,11 +127,15 @@ def mine_fused(db: DBMart, threshold: int, budget_bytes: int = 1 << 28,
 
     ``telemetry`` gets the spans ``fit.pass1``, one ``fit.pass2`` per
     chunk with the phases ``.dispatch`` (enqueue of pairgen and the
-    screen), ``.wait``, ``.fetch`` and ``.compact`` inside (the last three
-    announced by ``sparsity.screen_survivors``), and ``fit.assemble``; and
-    the counters ``fit.pairs`` (real pairs), ``fit.pass1.slots`` (pair
-    slots pass 1 computes, padding included) and ``fit.pass2.fetch_bytes``
-    — host integers from shapes, never a device read.
+    screen), ``.wait`` (until the screen's survivor total is on the host),
+    ``.compact`` (``survivors``, ``capacity``: the device compaction) and
+    ``.fetch`` (``bytes``: the copy of the compacted buffers) inside, the
+    last three announced by ``sparsity.screen_survivors``, and
+    ``fit.assemble`` (joining the chunks; a single chunk is not copied);
+    and the counters ``fit.pairs`` (real pairs), ``fit.pass1.slots`` (pair
+    slots pass 1 computes, padding included), ``fit.pass2.capacity``
+    (slots of the compacted buffers) and ``fit.pass2.fetch_bytes`` (their
+    bytes) — host integers, from shapes and the survivor totals.
 
     Returns compacted numpy {seq, dur, patient} (every row real) plus the
     global 'counts' table.
@@ -140,6 +144,7 @@ def mine_fused(db: DBMart, threshold: int, budget_bytes: int = 1 << 28,
 
     tracer, m = telemetry.tracer, telemetry.metrics
     fetch_bytes = m.counter("fit.pass2.fetch_bytes")
+    capacity = m.counter("fit.pass2.capacity")
     nevents = np.asarray(db.nevents)
     if telemetry.enabled:
         n = nevents.astype(np.int64)
@@ -158,24 +163,21 @@ def mine_fused(db: DBMart, threshold: int, budget_bytes: int = 1 << 28,
     for ch in chunks:
         with tracer.span("fit.pass2", patients=ch.n_patients,
                          E=ch.max_events), \
-                _Phases(tracer, fetch_bytes) as phase:
+                _Phases(tracer, fetch_bytes, capacity) as phase:
             phase("dispatch")
             sub = db.slice_patients(ch.start, ch.stop, ch.max_events)
             mined = mining.mine(sub.phenx, sub.date, sub.nevents, codec=codec,
                                 fuse_duration=fuse_duration,
                                 bucket_days=bucket_days, backend=backend,
                                 metrics=m)
-            P = mined.seq.shape[0]
-            pat = np.broadcast_to(
-                np.arange(ch.start, ch.start + P, dtype=np.int32).reshape(
-                    (P,) + (1,) * (mined.seq.ndim - 1)), mined.seq.shape)
-            part = sparsity.screen_survivors(
+            pat = np.arange(ch.start, ch.stop, dtype=np.int32)
+            parts.append(sparsity.screen_survivors(
                 mined.seq, mined.dur, pat, counts, threshold, n_buckets_log2,
-                mask=mined.mask, phase=phase)
-            phase.close(survivors=len(part[0]))
-        parts.append(part)
+                mask=mined.mask, phase=phase))
+            del sub, mined      # the next chunk's planes need the room
     with tracer.span("fit.assemble"):
-        cat = lambda k, dt: (np.concatenate([p[k] for p in parts]) if parts
+        cat = lambda k, dt: (parts[0][k] if len(parts) == 1 else
+                             np.concatenate([p[k] for p in parts]) if parts
                              else np.zeros(0, dt))
         out = {"seq": cat(0, np.int64), "dur": cat(1, np.int32),
                "patient": cat(2, np.int32), "counts": counts}
@@ -185,21 +187,24 @@ def mine_fused(db: DBMart, threshold: int, budget_bytes: int = 1 << 28,
 class _Phases:
     """The consecutive phase spans of one pass-2 chunk: ``phase(name,
     **args)`` ends the open phase and begins ``fit.pass2.<name>``, counting
-    a ``bytes`` arg on ``fetch_bytes``; ``close(**args)`` ends the last,
-    as leaving the ``with`` block does on an exception."""
+    a ``bytes`` arg on ``fetch_bytes`` and a ``capacity`` arg on
+    ``capacity``; leaving the ``with`` block ends the last, also on an
+    exception."""
 
-    def __init__(self, tracer, fetch_bytes):
-        self.tracer, self.fetch_bytes, self.open = tracer, fetch_bytes, None
+    def __init__(self, tracer, fetch_bytes, capacity):
+        self.tracer, self.open = tracer, None
+        self.counters = {"bytes": fetch_bytes, "capacity": capacity}
 
     def __call__(self, name, **args):
         self.close()
-        if "bytes" in args:
-            self.fetch_bytes.inc(args["bytes"])
+        for arg, counter in self.counters.items():
+            if arg in args:
+                counter.inc(args[arg])
         self.open = self.tracer.begin("fit.pass2." + name, **args)
 
-    def close(self, **args):
+    def close(self):
         if self.open is not None:
-            self.tracer.finish(self.open, **args)
+            self.tracer.finish(self.open)
             self.open = None
 
     def __enter__(self):

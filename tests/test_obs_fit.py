@@ -10,16 +10,14 @@ import pytest
 
 from repro import obs
 from repro.api import MiningConfig, MiningSession
-from repro.core import chunking, mining
+from repro.core import chunking, mining, sparsity
 from repro.kernels.tspm_fused import ops as fused_ops
 from tests.conftest import random_dbmart
 
 H = 10      # a small table, so buckets collide and the screen drops pairs
 
-PASS2_PHASES = ["fit.pass2.dispatch", "fit.pass2.wait", "fit.pass2.fetch",
-                "fit.pass2.compact"]
-#: bytes one pair slot moves device -> host in pass 2: keep, seq, dur
-FETCH_BYTES_PER_SLOT = 1 + 8 + 4
+PASS2_PHASES = ["fit.pass2.dispatch", "fit.pass2.wait", "fit.pass2.compact",
+                "fit.pass2.fetch"]
 
 
 def _db(seed=11, n_patients=14, max_events=14):
@@ -88,26 +86,35 @@ def test_fit_counters_closed_form(budget):
     P, E = db.phenx.shape
     assert snap["fit.pairs"] == int(mining.count_sequences(db.nevents))
     assert snap["fit.pass1.slots"] == P * E * E       # jnp blocks, no padding
-    # the jnp backend mines the packed triangle of each chunk
-    slots = sum(ch.n_patients * mining.n_pairs(ch.max_events)
-                for ch in _chunks(db, budget))
-    assert snap["fit.pass2.fetch_bytes"] == FETCH_BYTES_PER_SLOT * slots
+    # pass 2 copies only each chunk's compacted buffers: capacity slots of
+    # SURVIVOR_BYTES, the capacity the least step that holds the survivors
     tr = s.trace()
+    compact = tr.find("fit.pass2.compact")
+    assert len(compact) == len(_chunks(db, budget))
+    for sp in compact:
+        n, cap = sp.args["survivors"], sp.args["capacity"]
+        assert cap == sparsity.survivor_capacity(n)
+        assert n <= cap <= 1.2 * n + sparsity.CAPACITY_GRANULE
+    assert sum(sp.args["survivors"] for sp in compact) == len(frame)
+    assert snap["fit.pass2.capacity"] == sum(sp.args["capacity"]
+                                             for sp in compact)
+    assert snap["fit.pass2.fetch_bytes"] == \
+        sparsity.SURVIVOR_BYTES * snap["fit.pass2.capacity"]
     assert sum(sp.args["bytes"] for sp in tr.find("fit.pass2.fetch")) \
         == snap["fit.pass2.fetch_bytes"]
-    assert sum(sp.args["survivors"] for sp in tr.find("fit.pass2.compact")) \
-        == len(frame)
 
 
 def test_kernel_backend_counts_dense_planes():
-    """Pairgen mines dense [P, E, E] planes, so pass 2 computes (and
-    fetches) P x E^2 slots a chunk; the fused counting kernel's slots are
-    its padded planes."""
+    """Pairgen mines dense [P, E, E] planes, which pass 2 compacts on the
+    device, so it copies only the survivors' capacity whatever the padding;
+    the fused counting kernel's slots are its padded planes."""
     db = _db(seed=3, n_patients=10, max_events=9)
     s, frame = _fit(db, backend="kernel")
     snap = s.metrics()
     P, E = db.phenx.shape
-    assert snap["fit.pass2.fetch_bytes"] == FETCH_BYTES_PER_SLOT * P * E * E
+    assert snap["fit.pass2.capacity"] == sparsity.survivor_capacity(len(frame))
+    assert snap["fit.pass2.fetch_bytes"] == \
+        sparsity.SURVIVOR_BYTES * snap["fit.pass2.capacity"]
     cp = fused_ops.counting_plan(P, E, H, "kernel")
     assert cp.use_kernel and cp.n_blocks == 1
     rows = -(-P // 8) * 8      # 10 patients padded to the kernel's pb = 8
@@ -143,19 +150,17 @@ def test_fused_fit_byte_identical_on_off(engine):
 def test_failed_chunk_closes_its_spans(monkeypatch):
     """An exception inside a pass-2 phase ends every span it was under, so
     the tracer's stack and the profiler's annotations are left closed."""
-    from repro.core import sparsity
-
     def failing(*a, **k):
-        raise RuntimeError("screen failed")
-    monkeypatch.setattr(sparsity, "screen_hash_from_counts", failing)
+        raise RuntimeError("compaction failed")
+    monkeypatch.setattr(sparsity, "_compact", failing)
     s = MiningSession(MiningConfig(screen="fused", threshold=2,
                                    n_buckets_log2=H, backend="jnp",
                                    telemetry=True))
-    with pytest.raises(RuntimeError, match="screen failed"):
+    with pytest.raises(RuntimeError, match="compaction failed"):
         s.fit(_db())
     tr = s.trace()
     assert [sp.name for sp in sorted(tr.spans, key=lambda sp: sp.t0)] == [
-        "session.fit", "fit.pass1", "fit.pass2", "fit.pass2.dispatch"]
+        "session.fit", "fit.pass1", "fit.pass2"] + PASS2_PHASES[:3]
     assert all(sp.t1 is not None for sp in tr.spans)
     assert tr.begin("next").parent is None      # nothing left open
 
