@@ -120,6 +120,14 @@ def hash_bucket(seq, n_buckets_log2: int):
     return (h & ((1 << n_buckets_log2) - 1)).astype(jnp.int32)
 
 
+def _hash_bucket_host(seq, n_buckets_log2: int) -> np.ndarray:
+    """``hash_bucket`` in numpy, bit for bit (the top H bits of the 64-bit
+    product): a host array of a new length compiles nothing."""
+    h = np.asarray(seq, np.int64).view(np.uint64) * np.uint64(HASH_MULT)
+    h >>= np.uint64(64 - n_buckets_log2)
+    return h
+
+
 def row_first_flags(sorted_rows):
     """First-occurrence flags on row-wise sorted sentinel-padded id rows —
     the per-patient dedup step shared by the batch screen and the streaming
@@ -274,7 +282,9 @@ def screen_survivors(seq, dur, patient, counts, threshold,
     of ``seq.shape[0]`` values, e.g. a [P, 1, 1] column).
 
     Where the data lives decides where it is compacted.  Host (numpy)
-    inputs, and flat ones, which have no rows, are indexed on the host.
+    inputs, and flat ones, which have no rows, are screened and indexed
+    on the host, in numpy: a live corpus has a new length at every
+    snapshot, and a device screen would compile for each.
     Device arrays are screened and compacted on the device, and only the
     survivors are copied back: ``phase(name, **args)`` is called as each
     host phase starts, so a caller can time them (``chunking.mine_fused``
@@ -290,10 +300,10 @@ def screen_survivors(seq, dur, patient, counts, threshold,
     patient = np.asarray(patient, np.int32).reshape(P)
     if not isinstance(seq, jax.Array) or seq.ndim < 2:
         seq = np.asarray(seq, np.int64)
-        mask = seq != SENTINEL if mask is None else mask
-        keep = np.asarray(screen_hash_from_counts(
-            seq, mask, jnp.asarray(counts), threshold,
-            n_buckets_log2)).reshape(-1)
+        keep = np.asarray(counts)[_hash_bucket_host(
+            seq, n_buckets_log2)] >= threshold
+        keep &= seq != SENTINEL if mask is None else np.asarray(mask, bool)
+        keep = keep.reshape(-1)
         patient = np.broadcast_to(
             patient.reshape((P,) + (1,) * (seq.ndim - 1)), seq.shape)
         return (seq.reshape(-1)[keep],

@@ -216,6 +216,10 @@ class StreamService(SnapshotQueries):
         self._m_events = m.counter("stream.events", **labels)
         self._m_pairs = m.counter("stream.pairs", **labels)
         self._m_retraces = m.counter("jit.retraces", **labels)
+        # device->host bytes of the tick's slab copies, and the history
+        # the delta kernel reads: sum of n_old + n_new over the slots
+        self._m_fetch_bytes = m.counter("stream.tick.fetch_bytes", **labels)
+        self._m_history = m.counter("stream.history_events", **labels)
         self._m_dispatch = m.histogram("stream.tick.dispatch_s", **labels)
         self._m_collect = m.histogram("stream.tick.collect_s", **labels)
         self._m_device = m.histogram("stream.tick.device_s", **labels)
@@ -323,7 +327,10 @@ class StreamService(SnapshotQueries):
             fuse_duration=self.fuse_duration, bucket_days=self.bucket_days,
             backend=self.backend, interpret=self.interpret,
             metrics=self.obs.metrics, labels=self._labels)
-        sketch_pending = self.sketch.update_begin(pids, mined.seq, mined.mask)
+        # the slots' new pairs bound how far their sets can grow
+        d = n_new.astype(np.int64)
+        sketch_pending = self.sketch.update_begin(
+            pids, mined.seq, mined.mask, max_new=d * n_old + d * (d - 1) // 2)
         t_disp = time.perf_counter()
         self.obs.tracer.finish(sp, patients=B, events=int(n_new.sum()))
         # the device span stays open across the async gap; tick_finish
@@ -351,9 +358,13 @@ class StreamService(SnapshotQueries):
         sp = self.obs.tracer.begin("tick.collect", cat="host",
                                    track=self.track)
         self.sketch.update_finish(pending.sketch_pending)
+        sp_fetch = self.obs.tracer.begin("tick.fetch", cat="host",
+                                         track=self.track)
         m = np.asarray(mined.mask).reshape(B, -1)
         seq = np.asarray(mined.seq).reshape(B, -1)
         dur = np.asarray(mined.dur).reshape(B, -1)
+        fetched = m.nbytes + seq.nbytes + dur.nbytes
+        self.obs.tracer.finish(sp_fetch, bytes=fetched)
         pat = np.broadcast_to(pids[:, None], m.shape)
         seq_m, dur_m = seq[m], dur[m]
         self._corpus.append((seq_m, dur_m, pat[m]))
@@ -389,6 +400,8 @@ class StreamService(SnapshotQueries):
         self._m_ticks.inc()
         self._m_events.inc(st.n_events)
         self._m_pairs.inc(st.n_pairs)
+        self._m_fetch_bytes.inc(fetched)
+        self._m_history.inc(int(pending.n_old.sum() + pending.n_new.sum()))
         self._m_dispatch.observe(st.dispatch_s)
         self._m_collect.observe(st.collect_s)
         self._m_device.observe(st.device_s)

@@ -253,6 +253,48 @@ def test_session_metrics_record_mining():
     assert len(s.trace().find("tick.collect")) == n_ticks
 
 
+def test_tick_fetch_span_and_stream_counters(monkeypatch):
+    """One ``tick.fetch`` span per tick, inside its ``tick.collect``;
+    ``stream.tick.fetch_bytes`` is the bytes of the slabs the ticks
+    mined (mask 1 B, id 8 B, duration 4 B a slot), the sum of the spans'
+    ``bytes``; ``stream.history_events`` is Σ n_old + n_new over every
+    tick's slots."""
+    from repro.stream import service as service_lib
+
+    slabs = []
+    real = service_lib.delta_lib.delta_mine
+
+    def recording(*a, **k):
+        mined = real(*a, **k)
+        slabs.append(int(np.prod(mined.seq.shape)))
+        return mined
+    monkeypatch.setattr(service_lib.delta_lib, "delta_mine", recording)
+
+    rng = np.random.default_rng(8)
+    s = MiningSession(MiningConfig(telemetry=True, tick_patients=6,
+                                   n_buckets_log2=H))
+    held = np.zeros(5, np.int64)
+    history, day = 0, 0
+    for _ in range(7):
+        for p in range(5):                 # one delta a patient a tick
+            n = int(rng.integers(1, 9))
+            s.submit(p, np.arange(day, day + n, dtype=np.int32),
+                     rng.integers(0, 12, n).astype(np.int32))
+            history += int(held[p]) + n
+            held[p] += n
+            day += n
+        s.service.tick()
+    snap = s.metrics()
+    tr = s.trace()
+    fetch, collect = tr.find("tick.fetch"), tr.find("tick.collect")
+    assert len(fetch) == len(collect) == snap["stream.ticks"] == 7
+    for f, c in zip(fetch, collect):
+        assert f.parent is c
+    assert snap["stream.tick.fetch_bytes"] == 13 * sum(slabs) \
+        == sum(f.args["bytes"] for f in fetch)
+    assert snap["stream.history_events"] == history
+
+
 # --- TickStats split (the overlapping-wall fix) -----------------------------
 
 def test_tick_stats_split_populated_without_telemetry():

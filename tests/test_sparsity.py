@@ -233,3 +233,17 @@ def test_flat_device_survivors_index_on_host():
     assert phases == [] and len(want[0]) > 0
     for g, w in zip(got, want):
         assert g.dtype == w.dtype and g.tobytes() == w.tobytes()
+
+
+@pytest.mark.parametrize("n_buckets_log2", [1, 10, 14, 20, 24])
+def test_host_hash_bucket_is_hash_bucket_bit_for_bit(n_buckets_log2):
+    """The host screen's numpy hash equals the device ``hash_bucket`` on
+    ids of every sign, the sentinel included."""
+    rng = np.random.default_rng(n_buckets_log2)
+    ids = np.concatenate([
+        rng.integers(-2**63, 2**63 - 1, 4000, dtype=np.int64),
+        rng.integers(0, 1 << 48, 4000).astype(np.int64),
+        np.asarray([0, 1, -1, encoding.SENTINEL], np.int64)])
+    got = sparsity._hash_bucket_host(ids, n_buckets_log2)
+    want = np.asarray(sparsity.hash_bucket(ids, n_buckets_log2))
+    np.testing.assert_array_equal(got, want)

@@ -317,3 +317,116 @@ def test_store_regrowth_keeps_history():
             continue
         gp, gd = st.history(k)
         assert gp.tolist() == ph and gd.tolist() == dt
+
+
+# --- a date-ordered encounter feed, checked against batch mining -----------
+
+def _feed(db, per_patient):
+    """Each patient's history cut into ``per_patient`` chronological
+    deltas, ordered by each delta's first date (ties by patient, index)."""
+    out = []
+    for p in range(db.n_patients):
+        n = int(db.nevents[p])
+        cuts = np.linspace(0, n, per_patient + 1).round().astype(int)
+        for i in range(per_patient):
+            if cuts[i + 1] > cuts[i]:
+                out.append((int(db.date[p, cuts[i]]), p, i, cuts[i],
+                            cuts[i + 1]))
+    return sorted(out)
+
+
+def _visible_reference(db, visible, threshold):
+    """Fused-screen survivors as sorted (patient, seq, dur) triples and the
+    bucket table, batch-mined over each patient's first ``visible[p]``
+    events."""
+    mined = mining.mine_triangular(db.phenx, db.date,
+                                   np.asarray(visible, np.int32))
+    seq, dur, pat, msk = (np.asarray(x) for x in mining.flatten(mined))
+    cnt = np.asarray(sparsity.local_bucket_counts(
+        np.asarray(mined.seq), np.asarray(mined.mask), H))
+    seq, dur, pat = seq[msk], dur[msk], pat[msk]
+    keep = cnt[np.asarray(sparsity.hash_bucket(seq, H))] >= threshold
+    return sorted(zip(pat[keep].tolist(), seq[keep].tolist(),
+                      dur[keep].tolist())), cnt
+
+
+def test_date_ordered_feed_equals_batch_while_it_runs():
+    """Forty patients, sixteen date-ordered deltas each, fed four slots a
+    tick through the session: at several points of the replay the
+    published frame and the sketch table equal batch mining of exactly the
+    visible events, while the set planes grow through several widths of
+    their family."""
+    from repro.api import MiningSession
+    from repro.stream.counts import set_width
+
+    rng = np.random.default_rng(15)
+    db = random_dbmart(rng, n_patients=40, max_events=64, n_codes=48)
+    thr = 3
+    s = MiningSession(screen="fused", threshold=thr, n_buckets_log2=H,
+                      tick_patients=4)
+    feed = _feed(db, 16)
+    submitted = np.zeros(db.n_patients, np.int64)
+    widths, checked = set(), 0
+    for i, (_, p, _, a, b) in enumerate(feed):
+        s.submit(p, db.date[p, a:b], db.phenx[p, a:b])
+        submitted[p] = b
+        if len(s.service.queue) >= 8:
+            s.service.tick()
+            widths.add(s.service.sketch.set_columns)
+        if i % 150 == 149 or i == len(feed) - 1:
+            frame = s.frame()
+            queued = np.zeros(db.n_patients, np.int64)
+            for d in s.service.queue:
+                queued[d.key] += len(d.dates)
+            want, cnt = _visible_reference(db, submitted - queued, thr)
+            seq, dur, pat, _ = frame.arrays()
+            assert sorted(zip(pat.tolist(), seq.tolist(),
+                              dur.tolist())) == want
+            np.testing.assert_array_equal(
+                np.asarray(s.service.sketch.counts), cnt)
+            checked += 1
+    s.run()
+    want, cnt = _visible_reference(db, db.nevents, thr)
+    seq, dur, pat, _ = s.frame().arrays()
+    assert sorted(zip(pat.tolist(), seq.tolist(), dur.tolist())) == want
+    assert checked >= 4
+    assert len(widths) >= 3, widths          # two growth steps at least
+    assert all(w == set_width(w, 64) for w in widths)
+
+
+def test_set_planes_grow_on_one_family_and_landing_keeps_other_rows():
+    """Every width the growth policy reaches is pad_multiple * 2**k, and
+    landing a tick's rows leaves every other patient's set as it was."""
+    import jax.numpy as jnp
+
+    from repro.core.encoding import SENTINEL
+    from repro.stream.counts import OnlineSupportSketch, set_width
+
+    rng = np.random.default_rng(3)
+    sk = OnlineSupportSketch(n_buckets_log2=H, pad_multiple=8)
+    model = [set() for _ in range(12)]
+    seen = set()
+    for step in range(40):
+        pids = rng.choice(12, size=int(rng.integers(1, 5)), replace=False)
+        T = int(rng.integers(1, 9)) * 8
+        seq = rng.integers(0, 40 + 40 * step, (len(pids), T)).astype(np.int64)
+        mask = rng.random((len(pids), T)) < 0.7
+        if step == 0:     # a first delta past twice the width: one jump
+            T = 100
+            seq = np.tile(np.arange(T, dtype=np.int64), (len(pids), 1))
+            mask = np.ones((len(pids), T), bool)
+        sk.update(pids, jnp.asarray(seq), jnp.asarray(mask))
+        for b, p in enumerate(pids):
+            model[p] |= set(seq[b][mask[b]].tolist())
+        C = sk.set_columns
+        seen.add(C)
+        assert C == set_width(C, 8) and C >= max(len(m) for m in model)
+        rows = np.asarray(sk.rows(np.arange(sk.n_patients)))
+        for p, ids in enumerate(model):
+            want = np.full(C, SENTINEL, np.int64)
+            want[:len(ids)] = sorted(ids)
+            np.testing.assert_array_equal(rows[p], want)
+            assert sk.n_distinct[p] == len(ids)
+    assert len(seen) >= 3, seen
+    assert [set_width(n, 64) for n in (0, 1, 64, 65, 29952)] == \
+        [64, 64, 64, 128, 32768]

@@ -101,3 +101,30 @@ def test_delta_kernel_is_lowerable_for_tpu_style_blocks(one_chip):
         lambda *a: delta_ops.delta_pairgen(*a, interpret=False),
         one_chip, ((8, 104), I32), ((8, 104), I32), ((8,), I32),
         ((8,), I32), ((8, 40), I32), ((8, 40), I32))
+
+
+#: the sketch's set-plane widths, 64 * 2**k up to 2**18 columns
+SET_WIDTHS = [64 << k for k in range(13)]
+
+
+@pytest.mark.parametrize("B", [16, 32])
+@pytest.mark.parametrize("C", SET_WIDTHS)
+def test_sketch_rows_and_land_compile_in_place_for_v5e(one_chip, B, C):
+    """Reading a wave's sets and landing its merged rows compile at every
+    width the sketch's growth policy reaches, for a 1,024-patient plane.
+    The land updates the donated planes in place and neither program
+    holds a copy of a plane (an int64 plane's scatter was refused at
+    width 29,952, and even donated it was split and joined whole)."""
+    from repro.stream import counts
+
+    P = 1024
+    plane = P * C * 4
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in
+            (((P, C), I32), ((P, C), I32), ((B,), I32))]
+    rows = counts.sketch_rows.lower(*args).compile().memory_analysis()
+    merged = jax.ShapeDtypeStruct((B, C + 4096), jnp.int64,
+                                  sharding=one_chip)
+    land = counts.sketch_land.lower(*args, merged).compile().memory_analysis()
+    assert rows.temp_size_in_bytes < max(plane // 8, 1 << 21)
+    assert land.temp_size_in_bytes < max(plane // 8, 1 << 21)
+    assert land.alias_size_in_bytes == 2 * plane
