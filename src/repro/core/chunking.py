@@ -125,15 +125,17 @@ def mine_fused(db: DBMart, threshold: int, budget_bytes: int = 1 << 28,
     themselves.  Byte-identical to mine + hash screen (keeping is per-id,
     so supports and canonical order are preserved).
 
-    ``telemetry`` gets the spans ``fit.pass1``, one ``fit.pass2`` per
-    chunk with the phases ``.dispatch`` (enqueue of pairgen and the
-    screen), ``.wait`` (until the screen's survivor total is on the host),
-    ``.compact`` (``survivors``, ``capacity``: the device compaction) and
-    ``.fetch`` (``bytes``: the copy of the compacted buffers) inside, the
-    last three announced by ``sparsity.screen_survivors``, and
-    ``fit.assemble`` (joining the chunks; a single chunk is not copied);
-    and the counters ``fit.pairs`` (real pairs), ``fit.pass1.slots`` (pair
-    slots pass 1 computes, padding included), ``fit.pass2.capacity``
+    ``telemetry`` gets the spans ``fit.pass1`` (``blocks``, and
+    ``widths``: its length groups' widths, one for a uniform cohort), one
+    ``fit.pass2`` per chunk with the phases ``.dispatch`` (enqueue of
+    pairgen and the screen), ``.wait`` (until the screen's survivor total
+    is on the host), ``.compact`` (``survivors``, ``capacity``: the device
+    compaction) and ``.fetch`` (``bytes``: the copy of the compacted
+    buffers) inside, the last three announced by
+    ``sparsity.screen_survivors``, and ``fit.assemble`` (joining the
+    chunks; a single chunk is not copied); and the counters ``fit.pairs``
+    (real pairs), ``fit.pass1.slots`` (pair slots pass 1 computes, its
+    length groups' padding included), ``fit.pass2.capacity``
     (slots of the compacted buffers) and ``fit.pass2.fetch_bytes`` (their
     bytes) — host integers, from shapes and the survivor totals.
 
@@ -149,11 +151,12 @@ def mine_fused(db: DBMart, threshold: int, budget_bytes: int = 1 << 28,
     if telemetry.enabled:
         n = nevents.astype(np.int64)
         m.counter("fit.pairs").inc(int(np.sum(n * (n - 1) // 2)))
-    cp = fused_ops.counting_plan(*db.phenx.shape, n_buckets_log2, backend,
+    cp = fused_ops.counting_plan(nevents, n_buckets_log2, backend,
                                  fuse_duration)
     m.counter("fit.pass1.slots").inc(cp.slots)
     with tracer.span("fit.pass1", impl="kernel" if cp.use_kernel else "jnp",
-                     blocks=cp.n_blocks, H=n_buckets_log2):
+                     blocks=len(cp.blocks), widths=list(cp.widths),
+                     H=n_buckets_log2):
         counts = np.asarray(fused_ops.fused_bucket_counts(
             db.phenx, db.date, db.nevents, codec=codec,
             fuse_duration=fuse_duration, bucket_days=bucket_days,

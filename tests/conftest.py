@@ -123,6 +123,13 @@ def brute_force():
     return brute_force_pairs
 
 
+#: the history lengths of the 32-patient Synthea benchmark cohort (the
+#: paper's Table 2 cut to 32 lognormal strata), in its patient order
+SYNTHEA_32 = [29, 102, 422, 267, 93, 44, 679, 165, 331, 59, 199, 484, 36, 851,
+              75, 12, 219, 113, 241, 124, 66, 51, 181, 137, 488, 296, 83, 566,
+              372, 21, 150, 3222]
+
+
 def random_dbmart(rng: np.random.Generator, n_patients=None, max_events=None,
                   n_codes=None, date_range=400):
     """Random numeric dbmart for property tests."""

@@ -8,11 +8,14 @@ values/timestamps, empty cohorts, adversarial hash collisions).  Plus the
 limb-hash unit contract (hash_parts == hash_bucket(pack) without ever
 forming the int64 id) and the roofline tile-selection pins.
 """
+import itertools
+import time
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from conftest import random_dbmart
+from conftest import SYNTHEA_32, random_dbmart
 from repro.analysis import roofline
 from repro.core import encoding, mining, sparsity
 from repro.kernels.tspm_fused import fused, ops, ref
@@ -154,6 +157,164 @@ def test_support_equals_threshold_edge(backend):
             m.seq, m.mask, got, t, H))
         np.testing.assert_array_equal(keep_fused, keep_oracle)
     assert keep_oracle.sum() == 0      # thr+1 kills the max bucket's ids
+
+
+# --- length-grouped counting plan -------------------------------------------
+def heavy_tailed_dbmart(rng, n_patients=14, n_codes=12):
+    """Lognormal history lengths (most short) plus 0- and 1-event patients
+    and one of 200 events, in shuffled order, padded to the longest."""
+    n = np.minimum(rng.lognormal(3.0, 1.0, n_patients - 3).astype(np.int32),
+                   300)
+    nev = rng.permutation(np.concatenate([[0, 1, 200], n]).astype(np.int32))
+    e_pad = -(-max(int(nev.max()), 1) // 8) * 8
+    phenx = rng.integers(0, n_codes, (len(nev), e_pad)).astype(np.int32)
+    date = np.sort(rng.integers(0, 900, (len(nev), e_pad)), axis=1)
+    from repro.data.dbmart import DBMart
+    return DBMart(phenx, date.astype(np.int32), nev, None)
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("codec", ("bit", "paper"))
+@pytest.mark.parametrize("fuse_duration", (False, True))
+def test_grouped_counts_equal_single_width_oracle(backend, codec,
+                                                  fuse_duration, monkeypatch):
+    """Blocks of length-sorted patients, each padded to its own group's
+    width, count exactly what one width for the whole cohort counts (every
+    saving cuts a group here, so the small cohort takes several)."""
+    monkeypatch.setattr(ops, "GROUP_SLOTS", 0)
+    rng = np.random.default_rng([BACKENDS.index(backend), len(codec),
+                                 int(fuse_duration)])
+    db = heavy_tailed_dbmart(rng)
+    H = 12
+    cp = ops.counting_plan(db.nevents, H, backend, fuse_duration)
+    assert len(cp.widths) > 1
+    want = np.asarray(ref.fused_bucket_counts_ref(
+        db.phenx, db.date, db.nevents, codec, fuse_duration, 30, H))
+    got = fused_counts(db, backend, codec=codec, fuse_duration=fuse_duration,
+                       n_buckets_log2=H)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_empty_rows_count_nothing(backend):
+    """7 patients in blocks of at most 4 take two blocks of 4: the row that
+    fills the second (row P, no events) adds nothing to the table."""
+    db = random_dbmart(np.random.default_rng(31), n_patients=7,
+                       max_events=20)
+    cp = ops.counting_plan(db.nevents, 12, backend, block_patients=4)
+    assert [int(np.sum(b.patients == 7)) for b in cp.blocks] == [0, 1]
+    np.testing.assert_array_equal(fused_counts(db, backend, block_patients=4),
+                                  oracle_counts(db))
+
+
+def _check_partition(cp, nevents):
+    """Every patient in exactly one block, no block narrower than its
+    longest history, at most MAX_WIDTHS widths and one block shape a
+    width, and fewer empty rows (row P) in a width's blocks than one
+    block holds."""
+    P = len(nevents)
+    rows = np.concatenate([b.patients for b in cp.blocks]) if cp.blocks \
+        else np.zeros(0, np.int64)
+    assert sorted(rows[rows != P].tolist()) == list(range(P))
+    for b in cp.blocks:
+        assert b.width in cp.widths
+        real = b.patients[b.patients != P]
+        assert b.width >= int(np.max(nevents[real], initial=0))
+    assert list(cp.widths) == sorted(set(cp.widths))
+    assert len(cp.widths) <= ops.MAX_WIDTHS
+    shapes = {(len(b.patients), b.width) for b in cp.blocks}
+    assert len(shapes) == len(cp.widths)
+    for n, w in shapes:
+        assert sum(int(np.sum(b.patients == P)) for b in cp.blocks
+                   if b.width == w) < n
+
+
+def test_counting_plan_synthea_lengths():
+    nev = np.array(SYNTHEA_32)
+    cp = ops.counting_plan(nev, 20, "jnp")
+    _check_partition(cp, nev)
+    assert cp.slots == sum(len(b.patients) * b.width ** 2 for b in cp.blocks)
+    assert cp.slots <= 20_000_000          # one width: 332,613,632
+    assert len(cp.widths) == ops.MAX_WIDTHS and cp.widths[-1] == 3224
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("seed", range(4))
+def test_counting_plan_partitions_the_cohort(backend, seed):
+    rng = np.random.default_rng(seed)
+    nev = np.concatenate([[0, 1, 0], rng.lognormal(4.0, 1.2, 200)
+                          .astype(np.int64)])
+    nev = rng.permutation(nev)
+    cp = ops.counting_plan(nev, 12, backend)
+    _check_partition(cp, nev)
+    step = 128 if backend == "kernel" else ops.JNP_WIDTH_MULTIPLE
+    assert all(w % step == 0 for w in cp.widths)
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_counting_plan_is_fast_at_table2_scale(backend):
+    """The paper's full Table 2 cohort, 35,000 lognormal histories (the
+    Synthea configuration's mu and sigma), plans in under 0.5 s of host
+    time (best of three)."""
+    rng = np.random.default_rng(35_000)
+    nev = np.maximum(rng.lognormal(5.0566, 1.1878, 35_000), 2).astype(
+        np.int64)
+    times = []
+    for _ in range(3):
+        t0 = time.process_time()
+        cp = ops.counting_plan(nev, 20, backend)
+        times.append(time.process_time() - t0)
+    assert min(times) < 0.5, times
+    _check_partition(cp, nev)
+
+
+@pytest.mark.parametrize("group_slots", (0, ops.GROUP_SLOTS))
+@pytest.mark.parametrize("seed", range(6))
+def test_counting_plan_is_the_least_padding(seed, group_slots, monkeypatch):
+    """The dynamic program's groups reach the least sum of patients x
+    width^2 plus GROUP_SLOTS a group that any cut of the sorted widths into
+    MAX_WIDTHS runs does (the fewest runs among equals)."""
+    monkeypatch.setattr(ops, "GROUP_SLOTS", group_slots)
+    rng = np.random.default_rng(seed)
+    nev = rng.integers(0, 120, int(rng.integers(1, 40)))
+    cp = ops.counting_plan(nev, 20, "jnp", block_patients=1 << 20)
+    w = np.sort(ops._padded_widths(nev, False, 128))
+    d = np.unique(w)
+    best = None
+    for k in range(min(ops.MAX_WIDTHS, len(d))):
+        for cuts in itertools.combinations(range(len(d) - 1), k):
+            tops = [d[c] for c in cuts] + [d[-1]]
+            slots = sum(int(t) ** 2 * int(np.sum(
+                (w <= t) & (w > (tops[i - 1] if i else -1))))
+                for i, t in enumerate(tops))
+            best = min(best or (slots + k * group_slots, k, slots),
+                       (slots + k * group_slots, k, slots))
+    assert (cp.slots, len(cp.widths)) == (best[2], best[1] + 1)
+
+
+def test_counting_plan_small_saving_keeps_one_width(monkeypatch):
+    """A group is cut off only where it saves more than GROUP_SLOTS pair
+    slots: 12 histories of 2..14 events save 2,304 of 3,072 slots by a
+    width-8 group, too few for one more program."""
+    nev = np.random.default_rng(5).integers(2, 15, 12)
+    cp = ops.counting_plan(nev, 10, "jnp")
+    assert cp.widths == (16,) and cp.slots == 12 * 16 * 16
+    monkeypatch.setattr(ops, "GROUP_SLOTS", 12 * 16 * 16 - 1728 - 1)
+    cp = ops.counting_plan(nev, 10, "jnp")
+    assert cp.widths == (8, 16) and cp.slots == 1728
+
+
+@pytest.mark.parametrize("n", (1, 8, 9, 129))
+def test_counting_plan_equal_lengths_one_width(n):
+    """A uniform cohort: one width, and the slots of one block width for
+    all, as before grouping (the kernel pads to its 128-lane tile)."""
+    nev = np.full(11, n)
+    jnp_plan = ops.counting_plan(nev, 12, "jnp")
+    w = -(-n // 8) * 8
+    assert jnp_plan.widths == (w,) and jnp_plan.slots == 11 * w * w
+    kern = ops.counting_plan(nev, 12, "kernel")
+    w = -(-n // 128) * 128
+    assert kern.widths == (w,) and kern.slots == 16 * w * w   # pb = 8
 
 
 # --- edge cases --------------------------------------------------------------

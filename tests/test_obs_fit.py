@@ -41,7 +41,9 @@ def _chunks(db, budget):
 # --- the span tree ----------------------------------------------------------
 
 @pytest.mark.parametrize("budget", [None, 12_000])
-def test_fit_span_tree(budget):
+def test_fit_span_tree(budget, monkeypatch):
+    # every saving cuts a width group, so the toy cohort takes two
+    monkeypatch.setattr(fused_ops, "GROUP_SLOTS", 0)
     db = _db()
     s, _ = _fit(db, budget)
     chunks = _chunks(db, budget)
@@ -51,7 +53,10 @@ def test_fit_span_tree(budget):
     kids = root["children"]
     assert [k["name"] for k in kids] == (
         ["fit.pass1"] + ["fit.pass2"] * len(chunks) + ["fit.assemble"])
-    assert kids[0]["args"] == {"impl": "jnp", "blocks": 1, "H": H}
+    cp = fused_ops.counting_plan(db.nevents, H, "jnp")
+    assert kids[0]["args"] == {"impl": "jnp", "blocks": len(cp.blocks),
+                               "widths": list(cp.widths), "H": H}
+    assert len(cp.widths) > 1       # lengths 0..14 pad to 8 and to 16
     for node, ch in zip(kids[1:-1], chunks):
         assert node["args"] == {"patients": ch.n_patients,
                                 "E": ch.max_events}
@@ -60,9 +65,12 @@ def test_fit_span_tree(budget):
         t = [(c["t0"], c["t1"]) for c in node["children"]]
         assert all(a1 <= b0 for (_, a1), (b0, _) in zip(t, t[1:]))
         assert node["t0"] <= t[0][0] and t[-1][1] <= node["t1"]
-    # every span arg is a host scalar: no device array is kept alive
+    # every span arg is a host scalar or a list of host integers: no
+    # device array is kept alive
     for sp in s.trace().spans:
-        assert all(type(v) in (int, str) for v in sp.args.values()), sp
+        assert all(type(v) in (int, str) or
+                   (type(v) is list and all(type(x) is int for x in v))
+                   for v in sp.args.values()), sp
 
 
 def test_span_durations_add_up_to_the_fit():
@@ -79,13 +87,19 @@ def test_span_durations_add_up_to_the_fit():
 # --- counters in closed form ------------------------------------------------
 
 @pytest.mark.parametrize("budget", [None, 12_000])
-def test_fit_counters_closed_form(budget):
+def test_fit_counters_closed_form(budget, monkeypatch):
+    monkeypatch.setattr(fused_ops, "GROUP_SLOTS", 0)
     db = _db()
     s, frame = _fit(db, budget)
     snap = s.metrics()
     P, E = db.phenx.shape
     assert snap["fit.pairs"] == int(mining.count_sequences(db.nevents))
-    assert snap["fit.pass1.slots"] == P * E * E       # jnp blocks, no padding
+    # jnp blocks: each patient's group width squared, padding included,
+    # and never more than one width for the whole cohort
+    cp = fused_ops.counting_plan(db.nevents, H, "jnp")
+    assert snap["fit.pass1.slots"] == cp.slots == sum(
+        len(b.patients) * b.width ** 2 for b in cp.blocks)
+    assert cp.slots < P * E * E
     # pass 2 copies only each chunk's compacted buffers: capacity slots of
     # SURVIVOR_BYTES, the capacity the least step that holds the survivors
     tr = s.trace()
@@ -111,16 +125,18 @@ def test_kernel_backend_counts_dense_planes():
     db = _db(seed=3, n_patients=10, max_events=9)
     s, frame = _fit(db, backend="kernel")
     snap = s.metrics()
-    P, E = db.phenx.shape
+    P = db.phenx.shape[0]
     assert snap["fit.pass2.capacity"] == sparsity.survivor_capacity(len(frame))
     assert snap["fit.pass2.fetch_bytes"] == \
         sparsity.SURVIVOR_BYTES * snap["fit.pass2.capacity"]
-    cp = fused_ops.counting_plan(P, E, H, "kernel")
-    assert cp.use_kernel and cp.n_blocks == 1
+    cp = fused_ops.counting_plan(db.nevents, H, "kernel")
+    assert cp.use_kernel and len(cp.blocks) == 1
+    assert cp.widths == (128,)  # every history pads to one lane tile
     rows = -(-P // 8) * 8      # 10 patients padded to the kernel's pb = 8
     assert snap["fit.pass1.slots"] == cp.slots == rows * 128 * 128
     pass1, = s.trace().find("fit.pass1")
     assert pass1.args["impl"] == "kernel"
+    assert pass1.args["widths"] == [128]
     _, ref = _fit(db, backend="kernel", telemetry=False)
     for a, b in zip(frame.arrays(), ref.arrays()):
         assert np.array_equal(np.asarray(a), np.asarray(b))
@@ -129,9 +145,13 @@ def test_kernel_backend_counts_dense_planes():
 @pytest.mark.parametrize("P,E,blk,want", [(10, 16, 4, 10 * 16 * 16),
                                           (0, 16, 4, 0)])
 def test_counting_plan_jnp_slots(P, E, blk, want):
-    cp = fused_ops.counting_plan(P, E, 20, "jnp", block_patients=blk)
+    """Equal lengths: one width, and P x E x E slots (blocks of 2 leave no
+    row empty, and blocks of 4 or 3 would)."""
+    cp = fused_ops.counting_plan(np.full(P, E), 20, "jnp", block_patients=blk)
     assert not cp.use_kernel
-    assert cp.slots == want and cp.n_blocks == -(-P // blk)
+    assert cp.slots == want
+    assert [len(b.patients) for b in cp.blocks] == [2] * (P // 2)
+    assert cp.widths == ((E,) if P else ())
 
 
 # --- exactness and absence --------------------------------------------------

@@ -18,8 +18,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from conftest import SYNTHEA_32
 from repro.kernels.tspm_delta import delta, ops as delta_ops
-from repro.kernels.tspm_fused import fused
+from repro.kernels.tspm_fused import fused, ops as fused_ops
 from repro.kernels.tspm_pairgen import ops as pairgen_ops, pairgen
 
 
@@ -128,3 +129,31 @@ def test_sketch_rows_and_land_compile_in_place_for_v5e(one_chip, B, C):
     assert rows.temp_size_in_bytes < max(plane // 8, 1 << 21)
     assert land.temp_size_in_bytes < max(plane // 8, 1 << 21)
     assert land.alias_size_in_bytes == 2 * plane
+
+
+def _pass1_block(sharding, P, E, patients, width):
+    """The jnp fallback's pass-1 program for one [patients, width] block of
+    a [P, E] cohort at the benchmark's table, 2^20 buckets."""
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in
+            (((P, E), I32), ((P, E), I32), ((P,), I32),
+             ((patients,), jnp.int64))]
+    return fused_ops._jnp_block_counts.lower(
+        *args, width=width, codec="bit", fuse_duration=False, bucket_days=30,
+        n_buckets_log2=20).compile().memory_analysis()
+
+
+def test_pass1_blocks_compile_for_v5e(one_chip):
+    """The length-grouped pass 1 of the 32-patient Synthea cohort compiles
+    at its narrowest and widest blocks, and the widest, [1, 3224], needs
+    less device memory than the int64 id plane alone of the [8, 3224]
+    block that one width for all ran (compiling that block itself takes
+    minutes)."""
+    cp = fused_ops.counting_plan(SYNTHEA_32, 20, "jnp")
+    shapes = sorted({(len(b.patients), b.width) for b in cp.blocks},
+                    key=lambda s: s[1])
+    assert shapes[-1] == (1, 3224) and shapes[0][1] < 3224
+    size = lambda m: (m.temp_size_in_bytes + m.argument_size_in_bytes
+                      + m.output_size_in_bytes)
+    narrow = _pass1_block(one_chip, 32, 3224, *shapes[0])
+    widest = _pass1_block(one_chip, 32, 3224, 1, 3224)
+    assert size(narrow) < size(widest) < 8 * 3224 * 3224 * 8
